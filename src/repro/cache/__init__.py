@@ -10,10 +10,11 @@ every L2 key includes.  Wiring lives in ``core.tapir._compile``: L1 miss
 from .digest import stable_digest
 from .disk import (FORMAT_VERSION, PIPELINE_VERSION, ProgramDiskCache,
                    atomic_write_bytes, atomic_write_json,
-                   enable_xla_disk_cache, suspend_xla_disk_cache)
+                   enable_xla_disk_cache, suspend_xla_disk_cache,
+                   xla_cache_dir)
 
 __all__ = [
     "FORMAT_VERSION", "PIPELINE_VERSION", "ProgramDiskCache",
     "atomic_write_bytes", "atomic_write_json", "enable_xla_disk_cache",
-    "stable_digest", "suspend_xla_disk_cache",
+    "stable_digest", "suspend_xla_disk_cache", "xla_cache_dir",
 ]

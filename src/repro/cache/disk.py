@@ -75,7 +75,10 @@ FORMAT_VERSION = 2
 #: 9: pyfunc nodes lower through a jit boundary (transpose-unit association
 #: for gradients) and the autodiff/gradient-program machinery landed —
 #: programs emitted by pipeline-8 for the same signature are stale.
-PIPELINE_VERSION = "repro-pipeline-9"
+#: 10: kernel impls lower through their custom-VJP wrappers with the
+#: interpret mode taken from the program's backend, no kernel binds under
+#: a mesh, and the gated MLP's hidden carries a replication constraint.
+PIPELINE_VERSION = "repro-pipeline-10"
 
 
 def _versions() -> dict:
@@ -85,39 +88,45 @@ def _versions() -> dict:
             "pipeline": PIPELINE_VERSION, "format": FORMAT_VERSION}
 
 
+#: jax's persistent compilation cache when ``JAX_COMPILATION_CACHE_DIR`` is
+#: unset: a fixed directory at the checkout root (gitignored).  Fixed, not
+#: temporary, because the path is part of what a later process must find.
+DEFAULT_XLA_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
+
 _XLA_CACHE_ENABLED = False
 
 
-def enable_xla_disk_cache(root: str) -> None:
-    """Point jax's own persistent compilation cache at ``<root>/xla``.
+def xla_cache_dir() -> str:
+    """Where jax's persistent compilation cache lives: the directory
+    ``JAX_COMPILATION_CACHE_DIR`` names, else ``DEFAULT_XLA_CACHE_DIR``."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or DEFAULT_XLA_CACHE_DIR
+
+
+def enable_xla_disk_cache() -> None:
+    """Turn on jax's own persistent compilation cache at ``xla_cache_dir()``.
 
     The L2 store covers region programs (the big AOT executables), but a
     cold process also pays dozens of small XLA compiles our tier never
     sees: eager primitive dispatches (zeros-init, indexing, argmax) and
-    outer-jit wrappers whose inputs are tracers.  jax already knows how to
-    persist those — keyed on its own HLO fingerprint + jaxlib version — so
-    a cache-enabled process gets both tiers warm from one directory tree.
-    First configuration wins; never overrides a user-set cache dir."""
+    outer-jit wrappers whose inputs are tracers.  jax persists those keyed
+    on its own HLO fingerprint + jaxlib version.  Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set, jax reads it itself and nothing
+    is set here."""
     global _XLA_CACHE_ENABLED
-    if _XLA_CACHE_ENABLED:
+    if _XLA_CACHE_ENABLED or os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         return
     import jax
-    try:
-        if jax.config.jax_compilation_cache_dir:   # user already chose one
-            _XLA_CACHE_ENABLED = True
-            return
-        jax.config.update("jax_compilation_cache_dir",
-                          os.path.join(root, "xla"))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        # the cache-used probe is sticky: once any compile ran (backend
-        # init, param setup) the "no cache dir" verdict is latched — reset
-        # so the next compile re-reads the config and opens our dir
-        from jax._src import compilation_cache
-        compilation_cache.reset_cache()
-        _XLA_CACHE_ENABLED = True
-    except Exception:
-        pass    # older jax without the knobs: L2 still works alone
+    from jax._src import compilation_cache
+    jax.config.update("jax_compilation_cache_dir", DEFAULT_XLA_CACHE_DIR)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    # the cache-used probe is sticky: once any compile ran (backend init,
+    # param setup) the "no cache dir" verdict is latched — reset so the
+    # next compile re-reads the config and opens the directory
+    compilation_cache.reset_cache()
+    _XLA_CACHE_ENABLED = True
 
 
 #: ``jax_enable_compilation_cache`` is process-global state: the suspend
@@ -149,13 +158,10 @@ def suspend_xla_disk_cache():
     every region compile funnels through here, and the publish-time
     load-back check in ``_l2_publish`` backstops anything that slips."""
     import jax
+    from jax._src import compilation_cache
     with _XLA_SUSPEND_LOCK:
-        try:
-            from jax._src import compilation_cache
-            active = (jax.config.jax_compilation_cache_dir
-                      and jax.config.jax_enable_compilation_cache)
-        except Exception:
-            active = False
+        active = (jax.config.jax_compilation_cache_dir
+                  and jax.config.jax_enable_compilation_cache)
         if not active:
             yield
             return

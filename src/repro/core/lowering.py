@@ -106,11 +106,16 @@ def _lower_matmul(node: Node, env: dict, backend: str,
     impl = node.schedule.impl or ("einsum" if exposed else "opaque")
     if impl == "fused_kernel" and w.ndim == 2:
         from repro.kernels import fused_matmul as fm
-        epi = [(fn, [env[e] for e in extras], at)
-               for fn, extras, at in node.epilogue]
-        return fm.ops.fused_matmul(x, w, epilogue=epi,
-                                   tile=node.schedule.tile,
-                                   out_dtype=out_dtype)
+        # the custom-VJP form, so a captured training step can
+        # differentiate through the kernel (core.autodiff runs jax.vjp
+        # over this lowering)
+        vals = tuple(env[e] for _, extras, _ in node.epilogue
+                     for e in extras)
+        stages = tuple((fn, len(extras), tuple(sorted(at.items())))
+                       for fn, extras, at in node.epilogue)
+        return fm.ops.fused_matmul_vjp(
+            x, w, vals, stages, out_dtype,
+            tuple(sorted(node.schedule.tile.items())), backend != "tpu")
 
     if w.ndim == 3 and node.attrs.get("stacked", False):
         # shared-input (QKV) fusion: one batched GEMM over stacked weights;
@@ -151,7 +156,7 @@ def _lower_attention(node: Node, env: dict, backend: str) -> Any:
         # the backward is the recompute-based flash gradient
         y = fa.ops.flash_attention_vjp(
             q, k, v, causal, node.schedule.tile.get("bq", 128),
-            node.schedule.tile.get("bkv", 128))
+            node.schedule.tile.get("bkv", 128), backend != "tpu")
     elif impl == "blockwise":
         from repro.kernels import flash_attention as fa
         # online-softmax over KV blocks (never materializes scores).  The
@@ -216,8 +221,10 @@ def _lower_linear_scan(node: Node, env: dict, backend: str) -> Any:
     out_dtype = node.ttype.dtype
     impl = node.schedule.impl or ("chunked" if exposed else "opaque")
     if impl == "kernel":
-        y = ls.ops.linear_scan(q, k, v, w, u=u,
-                               chunk=node.schedule.tile.get("chunk", 128))
+        # custom-VJP form: differentiable inside a captured training step
+        y = ls.ops.linear_scan_vjp(q, k, v, w, u,
+                                   node.schedule.tile.get("chunk", 128),
+                                   backend != "tpu")
     elif impl == "chunked":
         # chunk-body intermediates are VMEM-resident in the Pallas kernel
         # on the TPU target (see launch.hlo_cost)

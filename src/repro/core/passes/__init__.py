@@ -12,6 +12,8 @@ from __future__ import annotations
 
 import dataclasses
 
+from repro.dist.sharding import current_mesh
+
 from ..ir import TaskGraph
 from ..schedule import CostModel, assign_early_heuristics, assign_schedules
 from .cse import cse
@@ -19,24 +21,10 @@ from .fusion import fuse_added_gemms, fuse_epilogues, fuse_shared_input
 from .inline import expose_libraries, seal_libraries
 
 
-_current_mesh = None
-
-
 def ambient_mesh():
     """The ambient mesh, or None.  Runs on the op-dispatch hot path (part
-    of every cache key), so the sharding import is resolved once and the
-    probe itself is two attribute lookups."""
-    global _current_mesh
-    if _current_mesh is None:
-        try:
-            from repro.dist.sharding import current_mesh as _cm
-        except Exception:
-            return None
-        _current_mesh = _cm
-    try:
-        return _current_mesh()
-    except Exception:
-        return None
+    of every cache key)."""
+    return current_mesh()
 
 
 def mesh_has_model_axis() -> bool:
@@ -48,8 +36,7 @@ def mesh_has_model_axis() -> bool:
 
 #: last (mesh object, fingerprint) — a mesh's axes/sizes are immutable,
 #: and the fingerprint sits on the op-dispatch hot path (every cache
-#: key), so the tuple build and jax-0.4's dict-allocating ``Mesh.shape``
-#: property run once per mesh, not once per op
+#: key), so the tuple build runs once per mesh, not once per op
 _fp_cache: tuple = (None, ())
 
 
@@ -67,7 +54,7 @@ def mesh_fingerprint() -> tuple:
     cached_m, fp = _fp_cache
     if cached_m is m:
         return fp
-    shape = m.shape   # jax 0.4's Mesh.shape rebuilds a dict per access
+    shape = m.shape
     fp = tuple((a, int(shape[a])) for a in m.axis_names)
     _fp_cache = (m, fp)
     return fp

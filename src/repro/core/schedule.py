@@ -25,20 +25,24 @@ benchmark.
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field
 from typing import Callable, Optional
 
+import jax
 import numpy as np
 
 from .ir import LIBRARY_OPS, Node, TaskGraph, dtype_bytes
-from repro.kernels.flash_attention.ops import attention_cost
+from repro.kernels.flash_attention.ops import (attention_cost,
+                                               kernel_unsupported)
 from repro.kernels.fused_matmul.ops import matmul_cost
 from repro.kernels.linear_scan.ops import SAFE_CHUNK, scan_cost
 
 
 @dataclass(frozen=True)
 class CostModel:
-    """TPU v5e-like target (the roofline constants used across the repo)."""
+    """Roofline constants of one target.  The defaults are TPU v5e's; the
+    device-derived table is ``COST_MODELS``."""
     name: str = "tpu_v5e"
     peak_flops: float = 197e12          # bf16 FLOP/s per chip
     hbm_bw: float = 819e9               # bytes/s per chip
@@ -90,6 +94,35 @@ CPU_COST_MODEL = CostModel(name="cpu_host", peak_flops=5e10, hbm_bw=2e10,
                            grain_flops=1 << 14, grain_bytes=1 << 16,
                            unroll_max_trip=8, spawn_s=2e-5,
                            score_passes_fused=4.0)
+
+#: Cost model per ``jax.devices()[0].device_kind``.  TPU v5e peaks from
+#: Google Cloud's "TPU v5e" documentation: 197 TFLOP/s bf16, 819 GB/s and
+#: 16 GiB of HBM per chip.  The CPU entry serves tests and CPU rehearsals.
+COST_MODELS: dict[str, CostModel] = {
+    "TPU v5 lite": CostModel(),
+    "cpu": CPU_COST_MODEL,
+}
+
+
+def cost_model_for(device_kind: Optional[str] = None) -> CostModel:
+    """The cost model of ``device_kind``, by default the kind of the device
+    this process computes on; a kind with no entry raises, since its peaks
+    would otherwise be assumed."""
+    kind = device_kind or _device_kind()
+    try:
+        return COST_MODELS[kind]
+    except KeyError:
+        raise ValueError(
+            f"no cost model for device kind {kind!r}; add its peaks "
+            f"to core.schedule.COST_MODELS (known: {sorted(COST_MODELS)})"
+        ) from None
+
+
+@functools.cache
+def _device_kind() -> str:
+    # read once: the cost model sits on the op-dispatch hot path (every
+    # cache key)
+    return jax.devices()[0].device_kind
 
 
 def _align(x: int, m: int) -> int:
@@ -238,6 +271,19 @@ class ImplCandidate:
     why: str = ""
 
 
+def _kernel_unavailable(backend: str,
+                        mesh_axes: Optional[dict]) -> Optional[str]:
+    """Why no Pallas kernel can run here (None when one can): Mosaic
+    kernels need the TPU target, and a program under an ambient mesh is
+    partitioned by GSPMD, which cannot partition their custom call (that
+    needs a shard_map)."""
+    if backend != "tpu":
+        return "pallas kernel needs the TPU target"
+    if mesh_axes:
+        return "GSPMD cannot partition a Mosaic kernel over the mesh"
+    return None
+
+
 def _fmt_s(t: float) -> str:
     return f"{t * 1e6:.1f}us" if t < 1e-3 else f"{t * 1e3:.2f}ms"
 
@@ -247,7 +293,8 @@ def attention_candidates(g: TaskGraph, node: Node, cm: CostModel,
                          ) -> list[ImplCandidate]:
     """Five ways to run scaled-dot-product attention, costed per shard:
 
-    * ``flash_kernel``       — Pallas flash kernel (TPU, S>1, no bias)
+    * ``flash_kernel``       — Pallas flash kernel (TPU, S>1, no bias, and
+                               causal or KV a whole number of blocks)
     * ``blockwise``          — online-softmax lax.scan over KV blocks; never
                                materializes scores but pays ``spawn_s`` per
                                block step (the Cilk spawn-overhead analogue)
@@ -277,21 +324,22 @@ def attention_candidates(g: TaskGraph, node: Node, cm: CostModel,
         sq, skv, d, node.ttype.dtype, cm)
     bkv = tile.get("bkv", 1024)
     compute_s = node.flops() / cm.peak_flops / shard
+    flash_why = kernel_unsupported(skv, node.attrs.get("causal", False),
+                                   has_bias, bkv)
 
     def base(impl: str):
         c = attention_cost(b, sq, skv, h, hkv, d, eb, impl, block_kv=bkv)
         return c, (c["flops"] / cm.peak_flops + c["io_bytes"] / cm.hbm_bw) / shard
 
     out: list[ImplCandidate] = []
-    if backend != "tpu":
-        out.append(ImplCandidate("flash_kernel", None,
-                                 "pallas kernel needs the TPU target"))
+    no_kernel = _kernel_unavailable(backend, mesh_axes)
+    if no_kernel:
+        out.append(ImplCandidate("flash_kernel", None, no_kernel))
     elif sq <= 1:
         out.append(ImplCandidate("flash_kernel", None,
                                  "decode (S=1): kernel q-grid degenerates"))
-    elif has_bias:
-        out.append(ImplCandidate("flash_kernel", None,
-                                 "kernel has no bias operand"))
+    elif flash_why:
+        out.append(ImplCandidate("flash_kernel", None, flash_why))
     else:
         _, t = base("flash_kernel")
         out.append(ImplCandidate("flash_kernel", t))
@@ -350,9 +398,9 @@ def matmul_candidates(g: TaskGraph, node: Node, cm: CostModel,
         return (c["flops"] / cm.peak_flops + c["io_bytes"] / cm.hbm_bw) / shard
 
     out: list[ImplCandidate] = []
-    if backend != "tpu":
-        out.append(ImplCandidate("fused_kernel", None,
-                                 "pallas kernel needs the TPU target"))
+    no_kernel = _kernel_unavailable(backend, mesh_axes)
+    if no_kernel:
+        out.append(ImplCandidate("fused_kernel", None, no_kernel))
     elif w_nd != 2:
         out.append(ImplCandidate("fused_kernel", None,
                                  "stacked/batched weights (kernel takes 2-D W)"))
@@ -383,9 +431,9 @@ def linear_scan_candidates(g: TaskGraph, node: Node, cm: CostModel,
                 + c["steps"] * cm.spawn_s) / shard
 
     out: list[ImplCandidate] = []
-    if backend != "tpu":
-        out.append(ImplCandidate("kernel", None,
-                                 "pallas kernel needs the TPU target"))
+    no_kernel = _kernel_unavailable(backend, mesh_axes)
+    if no_kernel:
+        out.append(ImplCandidate("kernel", None, no_kernel))
     else:
         out.append(ImplCandidate("kernel", roof("kernel")))
     out.append(ImplCandidate("chunked", roof("chunked")))

@@ -63,7 +63,7 @@ import numpy as np
 from .ir import TaskGraph, TensorType
 from .lowering import emit
 from .passes import mesh_fingerprint, run_pipeline
-from .schedule import CPU_COST_MODEL, CostModel
+from .schedule import CostModel, cost_model_for
 
 # ---------------------------------------------------------------------------
 # Config
@@ -114,7 +114,7 @@ class TapirConfig:
     def resolved_cost_model(self) -> CostModel:
         if self.cost_model is not None:
             return self.cost_model
-        return CostModel() if self.resolved_backend() == "tpu" else CPU_COST_MODEL
+        return cost_model_for()
 
 
 _tls = threading.local()
@@ -193,7 +193,7 @@ def _l2_for(cfg: TapirConfig):
         _L2_INSTANCES[k] = l2
         if cfg.cache_mode == "readwrite":
             # warm the small compiles too (eager dispatches, outer jits)
-            enable_xla_disk_cache(cfg.program_cache_dir)
+            enable_xla_disk_cache()
     return l2
 
 
@@ -1342,7 +1342,7 @@ def _build_multi_linear(g: TaskGraph, xi: int, wis: Sequence[int],
 
 
 def _build_gated_mlp(g: TaskGraph, xi: int, wgi: int, wui: int, wdi: int,
-                     activation: str) -> int:
+                     activation: str, gather_hidden: bool = False) -> int:
     x_t = g.nodes[xi].ttype
     f = g.nodes[wgi].ttype.shape[-1]
     hid_t = TensorType(tuple(x_t.shape[:-1]) + (f,), x_t.dtype)
@@ -1352,7 +1352,13 @@ def _build_gated_mlp(g: TaskGraph, xi: int, wgi: int, wui: int, wdi: int,
     mu = g.add("matmul", (xi, wui), hid_t, pdims=_pd(hid_t),
                rdims=(("k", k),), k=k)
     act = g.add("ew", (mg,), hid_t, pdims=_pd(hid_t), fn=activation)
-    prod = g.add("ew", (act, mu), hid_t, pdims=_pd(hid_t), fn="mul")
+    # gather_hidden: replicate the hidden before the down-projection, for
+    # callers that keep wd replicated (slot serving); GSPMD would otherwise
+    # split wd's contraction and all-reduce partial sums in the activation
+    # dtype, reordering float adds.  Inert off-mesh.
+    prod = g.add("ew", (act, mu), hid_t, pdims=_pd(hid_t), fn="mul",
+                 sharding=(None,) * len(hid_t.shape) if gather_hidden
+                 else None)
     out_t = TensorType(tuple(x_t.shape[:-1]) +
                        (g.nodes[wdi].ttype.shape[-1],), x_t.dtype)
     return g.add("matmul", (prod, wdi), out_t, pdims=_pd(out_t),
@@ -1524,18 +1530,21 @@ def multi_linear(x, ws: Sequence, bs: Optional[Sequence] = None):
     return _execute(sig, build, inputs)
 
 
-def gated_mlp(x, w_gate, w_up, w_down, activation: str = "silu"):
+def gated_mlp(x, w_gate, w_up, w_down, activation: str = "silu",
+              gather_hidden: bool = False):
     """SwiGLU MLP: down( act(x@w_gate) * (x@w_up) ).  Gate/up share input ->
-    fused into one GEMM; the mul and the down-proj epilogue fuse too."""
+    fused into one GEMM; the mul and the down-proj epilogue fuse too.
+    ``gather_hidden`` replicates the hidden under a mesh, so a replicated
+    ``w_down`` contracts it whole on every device."""
     reg = _active_region()
     if reg is not None:
         out = _build_gated_mlp(reg.g, reg.nid_of(x), reg.nid_of(w_gate),
                                reg.nid_of(w_up), reg.nid_of(w_down),
-                               activation)
+                               activation, gather_hidden)
         return reg.handle(out)
 
     sig = ("gated_mlp", x.shape, str(x.dtype), w_gate.shape, w_down.shape,
-           activation)
+           activation, gather_hidden)
     inputs = {"x": x, "wg": w_gate, "wu": w_up, "wd": w_down}
 
     def build(g: TaskGraph):
@@ -1543,7 +1552,8 @@ def gated_mlp(x, w_gate, w_up, w_down, activation: str = "silu"):
         wg = g.add_input("wg", _tt(w_gate))
         wu = g.add_input("wu", _tt(w_up))
         wd = g.add_input("wd", _tt(w_down))
-        g.set_outputs([_build_gated_mlp(g, xi, wg, wu, wd, activation)])
+        g.set_outputs([_build_gated_mlp(g, xi, wg, wu, wd, activation,
+                                        gather_hidden)])
 
     return _execute(sig, build, inputs)[0]
 

@@ -16,7 +16,6 @@ silently dropping constraints.
 """
 from __future__ import annotations
 
-from . import compat  # noqa: F401  (installs jax.set_mesh shim on old jax)
 from .fault import (Fault, FaultInjector, FaultTolerantLoop,
                     ScriptedFaultInjector, StragglerWatchdog)
 from .sharding import (batch_pspec, configure_rules, current_mesh,

@@ -18,8 +18,6 @@ import jax
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from . import compat
-
 # logical axis -> physical mesh axis (None = replicated).  "batch" is
 # special-cased: it shards over the data-parallel axes (pod, data).
 _RULES: dict[str, Optional[str]] = {
@@ -43,27 +41,13 @@ def configure_rules(**kwargs) -> dict:
     return prev
 
 
-try:  # legacy ``with mesh:`` context lookup — imported once, not per call
-    from jax._src import mesh as _mesh_lib
-except Exception:  # pragma: no cover - jax internals moved
-    _mesh_lib = None
-
-
 def current_mesh():
-    """The ambient mesh: the ``jax.set_mesh`` shim's mesh, else the legacy
-    ``with mesh:`` context's physical mesh, else None.  Called on the op
-    dispatch hot path (cache keys), so it must stay allocation-free."""
-    m = compat.ambient_mesh()
-    if m is not None and not getattr(m, "empty", False):
-        return m
-    if _mesh_lib is not None:
-        try:
-            m = _mesh_lib.thread_resources.env.physical_mesh
-            if m is not None and not m.empty:
-                return m
-        except Exception:
-            pass
-    return None
+    """The (abstract) mesh set by ``jax.set_mesh``, or None.  Abstract, so
+    it reads the same inside and outside ``jax.jit``: axis names and sizes
+    are all the cache keys and sharding constraints need.  Called on the
+    op dispatch hot path (cache keys), so it must stay allocation-free."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def _axes_size(mesh, axes: Sequence[str]) -> int:

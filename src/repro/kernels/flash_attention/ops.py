@@ -1,9 +1,11 @@
 """jit'd public wrapper for flash attention.
 
 Layout plumbing ([B,S,H,D] <-> [B,H,S,D]), block-size clamping + padding,
-interpret-mode fallback, custom VJP (backward is the standard recompute-
-based flash gradient, expressed with the jnp oracle so it is correct on
-every backend; a dedicated backward kernel is a TPU-side optimization)."""
+custom VJP (backward is the standard recompute-based flash gradient,
+expressed with the jnp oracle so it is correct on every backend; a
+dedicated backward kernel is a TPU-side optimization).  ``interpret`` is
+the caller's choice; shapes the kernel cannot run raise (the impl registry
+rules them out first, see ``kernel_unsupported``)."""
 from __future__ import annotations
 
 from functools import partial
@@ -19,24 +21,30 @@ def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
-def flash_attention(q, k, v, causal: bool = False, bias=None,
-                    block_q: int = 128, block_kv: int = 128,
-                    interpret=None):
+def kernel_unsupported(skv: int, causal: bool, has_bias: bool,
+                       block_kv: int) -> str:
+    """Why the kernel cannot run this attention ('' when it can): it has
+    no bias operand, and without the causal mask padded keys would take
+    softmax weight."""
+    if has_bias:
+        return "kernel has no bias operand"
+    if not causal and skv % min(block_kv, _round_up(skv, 128)):
+        return "non-causal with padded KV blocks"
+    return ""
+
+
+def flash_attention(q, k, v, causal: bool = False, block_q: int = 128,
+                    block_kv: int = 128, interpret: bool = False):
     """q: [B, Sq, Hq, D]; k, v: [B, Skv, Hkv, D].  Returns [B, Sq, Hq, D]."""
-    if bias is not None:
-        # bias paths use the composite (rare: relative-position biases)
-        return ref.attention_ref(q, k, v, causal=causal, bias=bias)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     b, sq, hq, d = q.shape
     _, skv, hkv, _ = k.shape
+    why = kernel_unsupported(skv, causal, False, block_kv)
+    if why:
+        raise ValueError(f"flash kernel: {why}")
 
     block_q = min(block_q, _round_up(sq, 128))
     block_kv = min(block_kv, _round_up(skv, 128))
     sqp, skvp = _round_up(sq, block_q), _round_up(skv, block_kv)
-    if not causal and skvp != skv:
-        # padded keys would receive softmax weight; use the composite
-        return ref.attention_ref(q, k, v, causal=False)
 
     qt = jnp.moveaxis(q, 2, 1)  # [B, H, S, D]
     kt = jnp.moveaxis(k, 2, 1)
@@ -107,17 +115,19 @@ def flash_attention_jnp(q, k, v, causal: bool = False, block_kv: int = 1024):
     return o.astype(q.dtype)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def flash_attention_vjp(q, k, v, causal=False, block_q=128, block_kv=128):
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def flash_attention_vjp(q, k, v, causal=False, block_q=128, block_kv=128,
+                        interpret=False):
     return flash_attention(q, k, v, causal=causal, block_q=block_q,
-                           block_kv=block_kv)
+                           block_kv=block_kv, interpret=interpret)
 
 
-def _fwd(q, k, v, causal, block_q, block_kv):
-    return flash_attention_vjp(q, k, v, causal, block_q, block_kv), (q, k, v)
+def _fwd(q, k, v, causal, block_q, block_kv, interpret):
+    return flash_attention_vjp(q, k, v, causal, block_q, block_kv,
+                               interpret), (q, k, v)
 
 
-def _bwd(causal, block_q, block_kv, res, do):
+def _bwd(causal, block_q, block_kv, interpret, res, do):
     q, k, v = res
     _, vjp = jax.vjp(lambda q_, k_, v_: ref.attention_ref(q_, k_, v_,
                                                           causal=causal),

@@ -1,9 +1,10 @@
 """jit'd public wrapper for the fused GEMM kernel.
 
 Handles: leading-batch flattening, padding to tile multiples, epilogue
-spec/operand splitting, interpret-mode fallback on non-TPU backends, and a
-custom VJP (the backward GEMMs route through plain XLA dots; the epilogue
-tail is differentiated by re-tracing the reference composite)."""
+spec/operand splitting, and a custom VJP (the backward GEMMs route through
+plain XLA dots; the epilogue tail is differentiated by re-tracing the
+reference composite).  ``interpret`` is the caller's choice: the TPU
+program runs the Mosaic kernel, tests ask for the interpreter."""
 from __future__ import annotations
 
 from functools import partial
@@ -41,11 +42,9 @@ def _classify(epilogue, out_shape):
 
 
 def fused_matmul(x, w, epilogue=None, tile=None, out_dtype=None,
-                 interpret=None):
+                 interpret: bool = False):
     """y = epilogue(x @ w);  x: [..., k], w: [k, n]."""
     out_dtype = out_dtype or x.dtype
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     lead = x.shape[:-1]
     k = x.shape[-1]
     n = w.shape[-1]
@@ -53,9 +52,11 @@ def fused_matmul(x, w, epilogue=None, tile=None, out_dtype=None,
     x2 = x.reshape(m, k)
 
     tile = tile or {}
-    bm = min(tile.get("bm", 128), _round_up(m, 8))
-    bn = min(tile.get("bn", 128), _round_up(n, 128))
-    bk = min(tile.get("bk", 512), _round_up(k, 128))
+    # blocks obey the TPU's (8, 128) tiling whatever the schedule asked for
+    # (a [B, 1, d] decode activation is scheduled with one row per tile)
+    bm = _round_up(min(tile.get("bm", 128), m), 8)
+    bn = _round_up(min(tile.get("bn", 128), n), 128)
+    bk = _round_up(min(tile.get("bk", 512), k), 128)
 
     spec, operands = _classify(epilogue, (m, n))
 
@@ -74,24 +75,40 @@ def fused_matmul(x, w, epilogue=None, tile=None, out_dtype=None,
 # -- differentiable wrapper ---------------------------------------------------
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def fused_matmul_vjp(x, w, epi_vals, epi_fns, out_dtype):
-    epilogue = [(fn, [v], at) for (fn, at), v in zip(epi_fns, epi_vals)]
-    return fused_matmul(x, w, epilogue=epilogue, out_dtype=out_dtype)
+def _split_epilogue(epi_stages, epi_vals):
+    """Rebuild ``[(fn, [operands], attrs)]`` from the static stage spec
+    ``((fn, n_operands, attrs_items), ...)`` and the flat operand tuple."""
+    out, i = [], 0
+    for fn, n, at in epi_stages:
+        out.append((fn, list(epi_vals[i:i + n]), dict(at)))
+        i += n
+    return out
 
 
-def _fwd(x, w, epi_vals, epi_fns, out_dtype):
-    y = fused_matmul_vjp(x, w, epi_vals, epi_fns, out_dtype)
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def fused_matmul_vjp(x, w, epi_vals, epi_stages, out_dtype, tile=(),
+                     interpret=False):
+    """Differentiable ``fused_matmul``.  ``epi_vals``: flat tuple of the
+    epilogue operands; ``epi_stages``: ``((fn, n_operands, attrs_items),
+    ...)``; ``tile``: ``tuple(tile.items())`` — all static and hashable."""
+    return fused_matmul(x, w, epilogue=_split_epilogue(epi_stages, epi_vals),
+                        tile=dict(tile), out_dtype=out_dtype,
+                        interpret=interpret)
+
+
+def _fwd(x, w, epi_vals, epi_stages, out_dtype, tile, interpret):
+    y = fused_matmul_vjp(x, w, epi_vals, epi_stages, out_dtype, tile,
+                         interpret)
     return y, (x, w, epi_vals)
 
 
-def _bwd(epi_fns, out_dtype, res, dy):
+def _bwd(epi_stages, out_dtype, tile, interpret, res, dy):
     x, w, epi_vals = res
 
     def f(x_, w_, vals_):
-        epilogue = [(fn, [v], at) for (fn, at), v in zip(epi_fns, vals_)]
-        return ref.fused_matmul_ref(x_, w_, epilogue=epilogue,
-                                    out_dtype=out_dtype)
+        return ref.fused_matmul_ref(
+            x_, w_, epilogue=_split_epilogue(epi_stages, vals_),
+            out_dtype=out_dtype)
 
     _, vjp = jax.vjp(f, x, w, epi_vals)
     return vjp(dy)

@@ -50,8 +50,8 @@ def _scan_kernel(q_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_ref, *,
     intra = jax.lax.dot_general(A, v, (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)
     if rwkv:
-        u = u_ref[0].astype(jnp.float32)      # [Dk]
-        bonus = jnp.sum(q * u[None, :] * k, axis=-1, keepdims=True)
+        u = u_ref[0].astype(jnp.float32)      # [1, Dk]
+        bonus = jnp.sum(q * u * k, axis=-1, keepdims=True)
         intra = intra + bonus * v
 
     # inter-chunk: read carry, emit contribution, update carry
@@ -69,7 +69,9 @@ def _scan_kernel(q_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_ref, *,
 
 def linear_scan_kernel(q, k, v, w, u, *, chunk: int, rwkv: bool,
                        interpret: bool = False):
-    """q/k/w: [BH, S, Dk], v: [BH, S, Dv], u: [BH, Dk]; S % chunk == 0."""
+    """q/k/w: [BH, S, Dk], v: [BH, S, Dv], u: [BH, 1, Dk]; S % chunk == 0.
+    ``u`` carries a unit middle dim so its block's last two dims equal the
+    array's, as the TPU's (8, 128) tiling rule requires."""
     BH, S, Dk = q.shape
     Dv = v.shape[-1]
     assert S % chunk == 0
@@ -83,7 +85,7 @@ def linear_scan_kernel(q, k, v, w, u, *, chunk: int, rwkv: bool,
             pl.BlockSpec((1, chunk, Dk), lambda i, n: (i, n, 0)),
             pl.BlockSpec((1, chunk, Dv), lambda i, n: (i, n, 0)),
             pl.BlockSpec((1, chunk, Dk), lambda i, n: (i, n, 0)),
-            pl.BlockSpec((1, Dk), lambda i, n: (i, 0)),
+            pl.BlockSpec((1, 1, Dk), lambda i, n: (i, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, chunk, Dv), lambda i, n: (i, n, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, S, Dv), v.dtype),

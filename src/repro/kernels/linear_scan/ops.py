@@ -95,11 +95,10 @@ def linear_scan_chunked(q, k, v, w, u=None, chunk: int = SAFE_CHUNK,
     return (o, S_fin) if return_state else o
 
 
-def linear_scan(q, k, v, w, u=None, chunk: int = 64, interpret=None):
-    """Pallas-kernel path (TPU target; interpret elsewhere)."""
+def linear_scan(q, k, v, w, u=None, chunk: int = 64,
+                interpret: bool = False):
+    """Pallas-kernel path (TPU target; ``interpret=True`` for tests)."""
     from . import kernel as _k
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     B, S, H, Dk = q.shape
     Dv = v.shape[-1]
     C = max(1, min(chunk, S))
@@ -117,11 +116,11 @@ def linear_scan(q, k, v, w, u=None, chunk: int = 64, interpret=None):
         vf = jnp.pad(vf, ((0, 0), (0, pad), (0, 0)))
         wf = jnp.pad(wf, ((0, 0), (0, pad), (0, 0)), constant_values=1.0)
     if u is None:
-        ub = jnp.zeros((B * H, Dk), jnp.float32)
+        ub = jnp.zeros((B * H, 1, Dk), jnp.float32)
         rwkv = False
     else:
         ub = jnp.broadcast_to(u.astype(jnp.float32)[None], (B, H, Dk)
-                              ).reshape(B * H, Dk)
+                              ).reshape(B * H, 1, Dk)
         rwkv = True
 
     o = _k.linear_scan_kernel(qf, kf, vf, wf, ub, chunk=C, rwkv=rwkv,
@@ -130,16 +129,16 @@ def linear_scan(q, k, v, w, u=None, chunk: int = 64, interpret=None):
     return jnp.moveaxis(o, 1, 2).astype(v.dtype)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(5,))
-def linear_scan_vjp(q, k, v, w, u, chunk=64):
-    return linear_scan(q, k, v, w, u=u, chunk=chunk)
+@partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def linear_scan_vjp(q, k, v, w, u, chunk=64, interpret=False):
+    return linear_scan(q, k, v, w, u=u, chunk=chunk, interpret=interpret)
 
 
-def _fwd(q, k, v, w, u, chunk):
-    return linear_scan_vjp(q, k, v, w, u, chunk), (q, k, v, w, u)
+def _fwd(q, k, v, w, u, chunk, interpret):
+    return linear_scan_vjp(q, k, v, w, u, chunk, interpret), (q, k, v, w, u)
 
 
-def _bwd(chunk, res, do):
+def _bwd(chunk, interpret, res, do):
     q, k, v, w, u = res
     _, vjp = jax.vjp(lambda *a: ref.linear_scan_ref(*a), q, k, v, w, u)
     return vjp(do)

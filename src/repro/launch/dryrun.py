@@ -41,6 +41,10 @@ from repro.dist.sharding import (batch_pspec, configure_rules,
 from repro.core.tapir import TapirConfig, use
 
 
+#: the chip whose cost model schedules the planned programs
+TARGET = "TPU v5 lite"
+
+
 def _attach(sds, sharding):
     return jax.ShapeDtypeStruct(sds.shape, sds.dtype, sharding=sharding)
 
@@ -89,6 +93,7 @@ def build_lowered(arch: str, shape_name: str, *, multi_pod: bool,
             if shape.kind == "train":
                 tcfg = TrainConfig(mode=mode, strategy=strategy,
                                    remat=remat, microbatches=mb,
+                                   target=TARGET,
                                    bf16_partials=bf16_partials,
                                    bf16_params_in_loss=bf16_params)
                 step, state_sh, _ = make_train_step(
@@ -100,7 +105,7 @@ def build_lowered(arch: str, shape_name: str, *, multi_pod: bool,
                 lowered = step.lower(state_sds, _batch_sds(ispecs, mesh))
             else:
                 scfg = ServeConfig(mode=mode, strategy="tp",
-                                   max_len=shape.seq_len)
+                                   max_len=shape.seq_len, target=TARGET)
                 p_sh = param_shardings(model.param_axes(), model.param_sds(),
                                        mesh, strategy="tp")
                 p_sds = jax.tree_util.tree_map(_attach, model.param_sds(),

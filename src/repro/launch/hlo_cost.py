@@ -29,8 +29,6 @@ from __future__ import annotations
 import re
 from dataclasses import dataclass, field
 
-import repro.dist.compat  # noqa: F401  (jax API shims for callers on old jax)
-
 from .hlo_stats import _DTYPE_BYTES, _crosses_pod
 
 _COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",

@@ -15,16 +15,12 @@ from __future__ import annotations
 
 import jax
 
-import repro.dist.compat  # noqa: F401  (jax.set_mesh shim on old jax)
-
 
 def _make_mesh(shape, axes):
-    """jax.make_mesh, passing axis_types=Auto only where the installed jax
-    supports it (the kwarg and AxisType arrived after 0.4.x)."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(shape, axes,
-                             axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """jax.make_mesh with Auto axes: GSPMD propagates shardings from the
+    captured constraints (jax's default for make_mesh is Explicit)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -64,12 +60,8 @@ def shrink_mesh(mesh, failed_device_id: int):
         if name != "model" and devs.shape[ax] > 1:
             keep = [i for i in range(devs.shape[ax]) if i != pos[0][ax]]
             new_devs = np.take(devs, keep, axis=ax)
-            Mesh = jax.sharding.Mesh
-            if hasattr(jax.sharding, "AxisType") and hasattr(
-                    mesh, "axis_types") and mesh.axis_types is not None:
-                return Mesh(new_devs, axis_names,
-                            axis_types=mesh.axis_types)
-            return Mesh(new_devs, axis_names)
+            return jax.sharding.Mesh(new_devs, axis_names,
+                                     axis_types=mesh.axis_types)
     raise ValueError(
         f"mesh {dict(zip(axis_names, devs.shape))} has no shrinkable "
         "data axis; cannot evict a device without breaking TP layout")

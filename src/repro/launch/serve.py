@@ -99,7 +99,7 @@ def main(argv=None):
     admit = args.admit_policy or ("slo" if args.deadline_s else "strict")
     eng = ServingEngine(model, params, batch=args.batch,
                         max_len=args.max_len,
-                        cfg=ServeConfig(mode=args.mode, target="cpu",
+                        cfg=ServeConfig(mode=args.mode,
                                         fault_injector=injector,
                                         admit_policy=admit,
                                         prefix_sharing=not args.no_prefix_sharing,

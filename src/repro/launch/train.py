@@ -55,7 +55,6 @@ def main(argv=None):
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--mode", default="tapir", choices=["tapir", "opaque"])
-    ap.add_argument("--target", default="cpu", choices=["cpu", "tpu"])
     ap.add_argument("--microbatches", type=int, default=1)
     ap.add_argument("--remat", default=None,
                     choices=["none", "dots", "full", "auto"],
@@ -83,7 +82,7 @@ def main(argv=None):
     mesh = make_mesh_for_devices()
     remat = args.remat or ("auto" if args.capture_step else "none")
     tcfg = TrainConfig(mode=args.mode, strategy="tp", remat=remat,
-                       microbatches=args.microbatches, target=args.target)
+                       microbatches=args.microbatches)
 
     if args.capture_step:
         # region-captured step: ONE joint fwd+bwd program, compiled on the

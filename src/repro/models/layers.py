@@ -116,7 +116,9 @@ def full_rope_table(max_len: int, head_dim: int, base: float = 10000.0,
     tab = _FULL_ROPE.get(key)
     if tab is None:
         cos, sin = rope_table(jnp.arange(Lb), head_dim, base, fraction)
-        tab = (cos, sin)
+        # uncommitted copies: computed under one engine's mesh, the table
+        # must still be a valid input under the next engine's mesh
+        tab = (jnp.asarray(np.asarray(cos)), jnp.asarray(np.asarray(sin)))
         _FULL_ROPE[key] = tab
     return tab
 

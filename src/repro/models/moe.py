@@ -113,12 +113,8 @@ class MoELM(DenseLM):
             # The EP shard_map path stays per-op only — shard_map's
             # per-shard python callable can't trace into the IR.
             return self._moe_ffn_traced(p, x)
-        mesh = None
-        try:
-            mesh = jax.sharding.get_abstract_mesh()
-        except Exception:
-            pass
-        if mesh is not None and not mesh.empty and "model" in mesh.axis_names:
+        mesh = jax.sharding.get_abstract_mesh()
+        if not mesh.empty and "model" in mesh.axis_names:
             n_model = mesh.shape["model"]
             dp = [a for a in ("pod", "data") if a in mesh.axis_names]
             dp_size = 1
@@ -191,10 +187,7 @@ class MoELM(DenseLM):
                       P("model", None, None), P("model", None, None),
                       P("model", None, None)),
             out_specs=P(batch_ax, None, None))
-        try:
-            f = jax.shard_map(ffn, check_vma=False, **sm_kwargs)
-        except TypeError:
-            f = jax.shard_map(ffn, check_rep=False, **sm_kwargs)
+        f = jax.shard_map(ffn, check_vma=False, **sm_kwargs)
         # cast expert weights to compute dtype BEFORE the shard_map
         # boundary: the FSDP gather at entry and the gradient psum the VJP
         # inserts at exit both move bf16 instead of f32 (2x less DCN)

@@ -3,6 +3,8 @@ chatglm3 and the internvl2 backbone).  All GEMM-heavy paths route through
 ``repro.core.tapir``; layer stacking is a late-scheduled ``scan_layers``."""
 from __future__ import annotations
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -113,10 +115,14 @@ class DenseLM(BaseModel):
         out = tapir.linear(o, p["wo"])
         return (out, kv_cache) if kv_cache is not None else (out, None)
 
-    def _mlp(self, p, x):
+    def _mlp(self, p, x, gather_hidden: bool = False):
+        """``gather_hidden``: the slot bodies keep ``wd`` replicated
+        (``pin_slot_params``), so its contraction must see the whole hidden
+        (see ``tapir.gated_mlp``)."""
         cfg = self.cfg
         if cfg.gated_mlp:
-            return tapir.gated_mlp(x, p["wg"], p["wu"], p["wd"], cfg.act)
+            return tapir.gated_mlp(x, p["wg"], p["wu"], p["wd"], cfg.act,
+                                   gather_hidden)
         return tapir.linear(tapir.linear(x, p["wu"], activation=cfg.act),
                             p["wd"])
 
@@ -349,8 +355,9 @@ class DenseLM(BaseModel):
                 "head": head, "embed": params["embed"]}
 
     def _slot_layer_params(self, params, cdt) -> list:
-        return [("dense",
-                 {k: v[i].astype(cdt) for k, v in params["blocks"].items()})
+        per_leaf = {k: _unstack(v, str(cdt))
+                    for k, v in params["blocks"].items()}
+        return [("dense", {k: vs[i] for k, vs in per_leaf.items()})
                 for i in range(self.cfg.n_layers)]
 
     def slot_param_axes(self) -> dict:
@@ -410,7 +417,7 @@ class DenseLM(BaseModel):
     def _slot_block_body(self, p, x, rope_cos, rope_sin, ck, cv, pos, ptab):
         x, ck, cv = self._slot_attn_body(p, x, rope_cos, rope_sin, ck, cv,
                                          pos, ptab)
-        x = x + self._mlp(p, self._norm(x, p["ln2"]))
+        x = x + self._mlp(p, self._norm(x, p["ln2"]), gather_hidden=True)
         return x, ck, cv
 
     def _slot_prefill_attn_body(self, p, x, rope_cos, rope_sin, ck, cv,
@@ -455,7 +462,7 @@ class DenseLM(BaseModel):
         x, ck, cv = self._slot_prefill_attn_body(
             p, x, rope_cos, rope_sin, ck, cv, pos_vec, phys_vec, off_vec,
             prow, vlen)
-        x = x + self._mlp(p, self._norm(x, p["ln2"]))
+        x = x + self._mlp(p, self._norm(x, p["ln2"]), gather_hidden=True)
         return x, ck, cv
 
     def _slot_head_body(self, hp, x):
@@ -542,6 +549,13 @@ class DenseLM(BaseModel):
         logits = head(sp["head"], hrow)
         cache["pos"] = cache["pos"].at[slot].set(plen)
         return logits, cache
+
+
+@partial(jax.jit, static_argnums=1)
+def _unstack(v, dtype: str) -> list:
+    """Per-layer slices of a stacked leaf, cast to ``dtype``: one program
+    per leaf shape instead of one eager slice per layer."""
+    return [v[i].astype(dtype) for i in range(v.shape[0])]
 
 
 def _decode_attention(q, ck, cv, valid_len):

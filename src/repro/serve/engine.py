@@ -58,7 +58,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.checkpoint.ckpt import restore_checkpoint, save_checkpoint
-from repro.core.schedule import CPU_COST_MODEL, CostModel
+from repro.core.schedule import cost_model_for
 from repro.core.tapir import (TapirConfig, cache_stats, invalidate_mesh,
                               use)
 from repro.dist.fault import Fault, FaultInjector, StragglerWatchdog
@@ -74,7 +74,9 @@ class ServeConfig:
     strategy: str = "tp"
     max_len: int = 2048
     greedy: bool = True
-    target: str = "tpu"     # schedule cost model: "tpu" | "cpu"
+    #: device kind whose cost model schedules the programs (a key of
+    #: ``core.schedule.COST_MODELS``); None: the device this process uses
+    target: Optional[str] = None
     # stateful region capture: each decode block (QKV, RoPE, KV-cache
     # writes, masked attention, MLP) traces into ONE TaskGraph and runs as
     # a single cached jit per step (cache donation applies at the outermost
@@ -156,9 +158,9 @@ class ServeConfig:
             # before any eager dispatch of the run: the small-compile tier
             # (jax's own persistent cache) only helps ops compiled after it
             from repro.cache import enable_xla_disk_cache
-            enable_xla_disk_cache(self.program_cache_dir)
-        cm = CostModel() if self.target == "tpu" else CPU_COST_MODEL
-        return TapirConfig(mode=self.mode, cost_model=cm,
+            enable_xla_disk_cache()
+        return TapirConfig(mode=self.mode,
+                           cost_model=cost_model_for(self.target),
                            regions=self.regions,
                            program_cache_dir=self.program_cache_dir,
                            cache_mode=self.cache_mode)
@@ -201,9 +203,9 @@ def slot_cache_shardings(model, mesh, slots: int, max_len: int,
                       model.slot_cache_axes(), mesh)
 
 
-def pin_slot_params(model, sp, mesh):
-    """``device_put`` the ``slot_params`` tree with its decode TP layout
-    committed up front, instead of GSPMD re-deciding a layout per program.
+def slot_param_shardings(model, sp, mesh):
+    """The decode TP layout of the ``slot_params`` tree ``sp`` (arrays or
+    shape structs): a NamedSharding per leaf, kind markers passed through.
 
     Only a leaf's LAST dim is sharded, and only when its logical axis maps
     to ``model`` and divides: the GEMM *N* dims (wq/wk/wv/wg/wu/lm head —
@@ -223,9 +225,19 @@ def pin_slot_params(model, sp, mesh):
         last = (None,) * (len(ax) - 1) + (ax[-1],) if ax else ()
         spec = logical_to_pspec(last, mesh, shape=v.shape)
         spec = tuple(s if s == "model" else None for s in spec)
-        return jax.device_put(v, NamedSharding(mesh, P(*spec)))
+        return NamedSharding(mesh, P(*spec))
 
     return jax.tree_util.tree_map(one, axes, sp, is_leaf=is_axes)
+
+
+def pin_slot_params(model, sp, mesh):
+    """``device_put`` the ``slot_params`` tree with its decode TP layout
+    (``slot_param_shardings``) committed up front, instead of GSPMD
+    re-deciding a layout per program."""
+    shardings = slot_param_shardings(model, sp, mesh)
+    return jax.tree_util.tree_map(
+        lambda v, sh: jax.device_put(v, sh) if hasattr(v, "shape") else v,
+        sp, shardings)
 
 
 class _EngineFault(Exception):
@@ -336,7 +348,13 @@ class ServingEngine:
         self.mesh = mesh
         #: scheduling stats of the most recent ``run``/``run_wave`` call
         self.last_stats: dict = {}
-        self._sp = None            # lazy pre-sliced slot params
+        #: pre-sliced slot params, built on the first slot run; from then
+        #: on they are the engine's only weights (``params`` is dropped)
+        self._sp = None
+        #: model FLOPs per token (2 x params), for the preemption roofline
+        self._flops_tok = 2.0 * sum(
+            int(np.prod(v.shape)) for v in jax.tree_util.tree_leaves(params)
+            if hasattr(v, "shape"))
         # slot scheduling runs wherever the family implements the slot
         # API — including TP meshes, where the slot regions capture under
         # the ambient mesh and replay their sharding constraints at
@@ -429,7 +447,12 @@ class ServingEngine:
         return tuple((a, int(shape[a])) for a in m.axis_names)
 
     def _build_slot_params(self):
+        """Slice the stacked params into per-layer slot leaves and drop the
+        engine's reference to the stacked tree, so one copy of the weights
+        stays resident (a caller that keeps no reference of its own frees
+        it here)."""
         sp = self.model.slot_params(self.params)
+        self.params = None
         if self.mesh is not None and getattr(self.mesh, "size", 1) > 1:
             sp = pin_slot_params(self.model, sp, self.mesh)
         return sp
@@ -564,7 +587,8 @@ class ServingEngine:
                 ft["mesh_shrinks"] += 1
         if self._mesh_fp() != old_fp:
             invalidate_mesh(old_fp)
-            self._sp = None         # re-pin params on the new mesh
+            if self._sp is not None:    # re-pin params on the new mesh
+                self._sp = pin_slot_params(self.model, self._sp, self.mesh)
 
     def _run_slots(self, requests, max_steps: int, continuous: bool):
         """Recovery loop around the slot session: a session runs until an
@@ -638,14 +662,6 @@ class ServingEngine:
         rs.ptab_host[s] = identity_row(s, rs.pool.pps)
         self._push_ptab(rs)
         rs.cache["pos"] = rs.cache["pos"].at[s].set(0)
-
-    def _flops_per_tok(self) -> float:
-        if getattr(self, "_flops_tok", None) is None:
-            self._flops_tok = 2.0 * sum(
-                int(np.prod(v.shape))
-                for v in jax.tree_util.tree_leaves(self.params)
-                if hasattr(v, "shape"))
-        return self._flops_tok
 
     def _page_bytes(self, rs: _SlotRunState) -> int:
         """Bytes one page copy moves (K+V, all layers)."""
@@ -800,13 +816,12 @@ class ServingEngine:
         length = int(np.asarray(rs.cache["pos"])[s])
         arm = cfg.preempt_mode
         if arm == "auto":
-            cm = CostModel() if cfg.target == "tpu" else CPU_COST_MODEL
             arm = preempt_cost(
-                cm, length=length,
+                cost_model_for(cfg.target), length=length,
                 prefix_len=pool.slot_bound[s] * pool.page_len,
                 n_out=len(victim.out), page_bytes=self._page_bytes(rs),
                 pps=pool.pps, page_len=pool.page_len,
-                model_flops_per_tok=self._flops_per_tok(),
+                model_flops_per_tok=self._flops_tok,
                 step_s=(wd.p50 or 1e-3)).arm
         if arm == "park":
             if pool.park(rs.cache, victim.rid, s, length):

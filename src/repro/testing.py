@@ -44,7 +44,9 @@ def run_mesh_subprocess(body: str, timeout: int = 580,
     ``result`` dict it populated."""
     script = (_preamble(devices) + textwrap.dedent(body)
               + "\nprint('RESULT::' + json.dumps(result))\n")
-    env = dict(os.environ, PYTHONPATH=SRC_DIR)
+    # a CPU-emulated mesh: the child never reaches for an accelerator the
+    # parent process may hold
+    env = dict(os.environ, PYTHONPATH=SRC_DIR, JAX_PLATFORMS="cpu")
     out = subprocess.run([sys.executable, "-c", script], env=env,
                          capture_output=True, text=True, timeout=timeout)
     assert out.returncode == 0, f"stderr:\n{out.stderr[-3000:]}"

@@ -25,7 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.core.schedule import CPU_COST_MODEL, CostModel
+from repro.core.schedule import cost_model_for
 from repro.core.tapir import TapirConfig, use
 from repro.dist.sharding import batch_pspec, param_shardings
 from repro.optim import AdamWConfig, adamw_init, adamw_update
@@ -38,10 +38,10 @@ class TrainConfig:
     remat: str = "full"               # none | dots | full
     microbatches: int = 1             # grad-accumulation factor
     compress_pod_grads: bool = False  # int8+EF on the pod axis
-    # which hardware the *schedule* (tiles, chunk sizes, grain) targets:
-    # "tpu" for dry-run/roofline (TPU is the target), "cpu" for wall-time
-    # benchmarks on this host.
-    target: str = "tpu"
+    # device kind whose cost model schedules the step (tiles, chunk sizes,
+    # grain; a key of ``core.schedule.COST_MODELS``) — the dry-run names
+    # the TPU it plans for; None: the device this process uses
+    target: Optional[str] = None
     bf16_partials: bool = False   # bf16 TP all-reduce payloads
     # cast params to compute dtype ONCE before the loss (outside the layer
     # scan): FSDP all-gathers then move bf16, not fp32 master weights —
@@ -49,8 +49,8 @@ class TrainConfig:
     bf16_params_in_loss: bool = False
 
     def tapir_config(self) -> TapirConfig:
-        cm = CostModel() if self.target == "tpu" else CPU_COST_MODEL
-        return TapirConfig(mode=self.mode, remat=self.remat, cost_model=cm,
+        return TapirConfig(mode=self.mode, remat=self.remat,
+                           cost_model=cost_model_for(self.target),
                            bf16_partials=self.bf16_partials)
 
 

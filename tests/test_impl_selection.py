@@ -20,8 +20,8 @@ import pytest
 from repro.core import tapir
 from repro.core.ir import TaskGraph, TensorType
 from repro.core.schedule import (CPU_COST_MODEL, CostModel, IMPL_REGISTRY,
-                                 attention_candidates, pick_gqa_impl,
-                                 pick_scan_chunk)
+                                 attention_candidates, cost_model_for,
+                                 pick_gqa_impl, pick_scan_chunk)
 from repro.core.tapir import TapirConfig, clear_cache, trace_graph, use
 from repro.kernels.linear_scan.ops import SAFE_CHUNK
 
@@ -272,3 +272,12 @@ def test_dump_schedule_and_explain():
     with use(_cfg()):
         tapir.attention(q, k, k)
     assert "impl=" in tapir.explain()
+
+
+def test_cost_model_follows_the_device_kind():
+    assert cost_model_for("cpu") is CPU_COST_MODEL
+    assert cost_model_for() is CPU_COST_MODEL     # tests compute on the CPU
+    v5e = cost_model_for("TPU v5 lite")
+    assert (v5e.peak_flops, v5e.hbm_bw) == (197e12, 819e9)
+    with pytest.raises(ValueError, match="no cost model"):
+        cost_model_for("TPU v99")       # its peaks would otherwise be assumed

@@ -115,7 +115,7 @@ def test_flash_attention_grad_matches_ref():
 
     def loss_k(q, k, v):
         return jnp.sum(fa_ops.flash_attention_vjp(
-            q, k, v, True, 32, 32) ** 2)
+            q, k, v, True, 32, 32, True) ** 2)
 
     def loss_r(q, k, v):
         return jnp.sum(fa_ref.attention_ref(q, k, v, causal=True) ** 2)
@@ -185,3 +185,45 @@ def test_linear_scan_grad_path():
     g2 = jax.grad(lambda q: jnp.sum(
         ls_ref.linear_scan_ref(q, k, v, w) ** 2))(q)
     np.testing.assert_allclose(g1, g2, rtol=2e-3, atol=2e-3)
+
+
+def test_fused_matmul_vjp_grad_matches_ref():
+    """The custom-VJP form the lowering binds for ``fused_kernel``: unary and
+    operand epilogue stages, gradients w.r.t. x, w and the operands."""
+    key = jax.random.PRNGKey(12)
+    x = jax.random.normal(key, (64, 96))
+    w = jax.random.normal(jax.random.fold_in(key, 1), (96, 128))
+    b = jax.random.normal(jax.random.fold_in(key, 2), (128,))
+    res = jax.random.normal(jax.random.fold_in(key, 3), (64, 128))
+    stages = (("add", 1, ()), ("silu", 0, ()), ("add", 1, ()))
+    tile = (("bm", 64), ("bn", 128), ("bk", 128))
+
+    def loss_k(x, w, b, res):
+        return jnp.sum(fm_ops.fused_matmul_vjp(
+            x, w, (b, res), stages, "float32", tile, True) ** 2)
+
+    def loss_r(x, w, b, res):
+        y = jax.nn.silu(x @ w + b) + res
+        return jnp.sum(y ** 2)
+
+    gk = jax.grad(loss_k, argnums=(0, 1, 2, 3))(x, w, b, res)
+    gr = jax.grad(loss_r, argnums=(0, 1, 2, 3))(x, w, b, res)
+    for a, r in zip(gk, gr):
+        np.testing.assert_allclose(a, r, rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("rwkv", [False, True])
+def test_linear_scan_vjp_grad_matches_ref(rwkv):
+    key = jax.random.PRNGKey(13)
+    B, S, H, D = 1, 32, 2, 8
+    q, k, v = (jax.random.normal(jax.random.fold_in(key, i), (B, S, H, D))
+               for i in range(3))
+    w = jnp.exp(jax.random.uniform(jax.random.fold_in(key, 3),
+                                   (B, S, H, D), minval=-2.0, maxval=-1e-3))
+    u = jax.random.normal(jax.random.fold_in(key, 4), (H, D)) if rwkv \
+        else None
+    gk = jax.grad(lambda q: jnp.sum(
+        ls_ops.linear_scan_vjp(q, k, v, w, u, 16, True) ** 2))(q)
+    gr = jax.grad(lambda q: jnp.sum(
+        ls_ref.linear_scan_ref(q, k, v, w, u=u) ** 2))(q)
+    np.testing.assert_allclose(gk, gr, rtol=2e-3, atol=2e-3)

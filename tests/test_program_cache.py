@@ -33,7 +33,6 @@ import pytest
 from hypothesis import given, settings, strategies as st
 from jax.sharding import Mesh
 
-import repro.dist  # noqa: F401  (installs the jax.set_mesh shim)
 from repro.cache import ProgramDiskCache, stable_digest
 from repro.core import tapir
 from repro.core.tapir import TapirConfig, _cfg_key, clear_cache, use

@@ -78,7 +78,10 @@ FORMAT_VERSION = 2
 #: 10: kernel impls lower through their custom-VJP wrappers with the
 #: interpret mode taken from the program's backend, no kernel binds under
 #: a mesh, and the gated MLP's hidden carries a replication constraint.
-PIPELINE_VERSION = "repro-pipeline-10"
+#: 11: each region program's module is named for its region
+#: (``jit_tapir_<region>``); an older entry would put the old name back
+#: into a device trace.
+PIPELINE_VERSION = "repro-pipeline-11"
 
 
 def _versions() -> dict:

@@ -49,6 +49,7 @@ becomes ONE region with zero per-step cache copies::
 from __future__ import annotations
 
 import functools
+import re
 import threading
 import time
 import weakref
@@ -59,6 +60,8 @@ from typing import Any, Callable, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.spans import span
 
 from .ir import TaskGraph, TensorType
 from .lowering import emit
@@ -153,6 +156,9 @@ _CACHE_STATS = {
     # by a fresh compile (cache problem degraded to a compile, not a wrong
     # answer)
     "l2_fallbacks": 0,
+    # ``parallel_region`` calls that traced their body instead of replaying
+    # a recorded program: the host stall a warm loop should never pay
+    "region_captures": 0,
 }
 #: optimized graphs by cache key — introspection for tests/benchmarks
 _GRAPHS: dict[tuple, TaskGraph] = {}
@@ -239,6 +245,10 @@ def _positional_jit(emitted: Callable, g: TaskGraph):
     def _positional(*argv):
         return emitted(dict(zip(names, argv)))
 
+    # the jit's name is the compiled module's (``jit_tapir_slot_head``):
+    # device traces and HLO dumps name each program by its region
+    _positional.__name__ = _positional.__qualname__ = \
+        "tapir_" + re.sub(r"\W", "_", g.name)
     return jax.jit(_positional, donate_argnums=pos), names
 
 
@@ -787,6 +797,9 @@ class _Region:
         self.cfg = cfg
         self.closed = False
         self.segments = 0
+        #: where the last segment's program came from: "memory" (L1),
+        #: "disk" (L2) or "compiled"
+        self.source = "none"
         self.g = TaskGraph(name)
         self._inp_by_id: dict[int, int] = {}
         self._inp_vals: list[Any] = []
@@ -847,10 +860,14 @@ class _Region:
         fn = _CACHE.get(key)
         if fn is None:
             _CACHE_STATS["misses"] += 1
+            compiled = _CACHE_STATS["compiled_programs"]
             fn = _compile(self.g, cfg, backend, key, jit=True,
                           example_inputs=inputs)
+            self.source = "compiled" if \
+                _CACHE_STATS["compiled_programs"] > compiled else "disk"
         else:
             _CACHE_STATS["hits"] += 1
+            self.source = "memory"
         self._last_fn = fn
         results = fn(inputs)
         for h, r in zip(outs, results):
@@ -963,34 +980,43 @@ def parallel_region(fn=None, *, name: Optional[str] = None):
                     _CACHE_STATS["hits"] += 1
                     return hit[2](leaves)
 
+            _CACHE_STATS["region_captures"] += 1
             r = _Region(name or getattr(f, "__name__", "region"), cfg)
-            argpos = {}
-            for i, v in enumerate(leaves):
-                if _is_arraylike(v):
-                    argpos.setdefault(id(v), i)
-            handles = [r.wrap(v) if _is_arraylike(v) else v for v in leaves]
-            targs, tkwargs = jax.tree_util.tree_unflatten(treedef, handles)
-            stack = _region_stack()
-            stack.append(r)
-            try:
-                out = f(*targs, **tkwargs)
-            except BaseException:
-                r.abandon()
-                raise
-            finally:
-                stack.pop()
-            out_leaves, out_treedef = jax.tree_util.tree_flatten(out)
-            pending = r._pending()
-            if pending:
-                r._run(pending)
-            r.closed = True
-            _maybe_cache_program(key, f, r, pending, out_leaves, out_treedef,
-                                 argpos)
-            return jax.tree_util.tree_map(
-                lambda v: v._concrete if isinstance(v, TracedTensor) else v,
-                out)
+            with span("tapir.capture", region=r.name) as sp:
+                out = _capture(f, r, key, leaves, treedef)
+                sp.set_metadata(source=r.source)
+            return out
         return wrapper
     return deco(fn) if fn is not None else deco
+
+
+def _capture(f, r: _Region, key, leaves, treedef):
+    """Trace ``f`` over ``leaves`` into ``r``, run its program (L1 hit, L2
+    load or compile) and record a replay for ``key``; returns ``f``'s
+    output with concrete arrays."""
+    argpos = {}
+    for i, v in enumerate(leaves):
+        if _is_arraylike(v):
+            argpos.setdefault(id(v), i)
+    handles = [r.wrap(v) if _is_arraylike(v) else v for v in leaves]
+    targs, tkwargs = jax.tree_util.tree_unflatten(treedef, handles)
+    stack = _region_stack()
+    stack.append(r)
+    try:
+        out = f(*targs, **tkwargs)
+    except BaseException:
+        r.abandon()
+        raise
+    finally:
+        stack.pop()
+    out_leaves, out_treedef = jax.tree_util.tree_flatten(out)
+    pending = r._pending()
+    if pending:
+        r._run(pending)
+    r.closed = True
+    _maybe_cache_program(key, f, r, pending, out_leaves, out_treedef, argpos)
+    return jax.tree_util.tree_map(
+        lambda v: v._concrete if isinstance(v, TracedTensor) else v, out)
 
 
 def _maybe_cache_program(key, f, r: _Region, pending, out_leaves,
@@ -1815,7 +1841,8 @@ def clear_cache() -> None:
     _PROVENANCE.clear()
     _CACHE_STATS.update(hits=0, misses=0, pipeline_s=0.0,
                         compiled_programs=0, l2_hits=0, l2_misses=0,
-                        l2_quarantined=0, l2_writes=0, l2_fallbacks=0)
+                        l2_quarantined=0, l2_writes=0, l2_fallbacks=0,
+                        region_captures=0)
 
 
 def invalidate_mesh(fingerprint: tuple) -> int:

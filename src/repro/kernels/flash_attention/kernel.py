@@ -108,4 +108,5 @@ def flash_attention_kernel(q, k, v, *, causal: bool, block_q: int,
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)

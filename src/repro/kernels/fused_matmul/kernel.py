@@ -90,4 +90,5 @@ def fused_matmul_kernel(x, w, epi_operands, epi_spec, *, bm, bn, bk,
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        name="fused_matmul",
     )(x, w, *epi_operands)

@@ -91,4 +91,5 @@ def linear_scan_kernel(q, k, v, w, u, *, chunk: int, rwkv: bool,
         out_shape=jax.ShapeDtypeStruct((BH, S, Dv), v.dtype),
         scratch_shapes=[pltpu.VMEM((Dk, Dv), jnp.float32)],
         interpret=interpret,
+        name="linear_scan",
     )(q, k, v, w, u)

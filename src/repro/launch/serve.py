@@ -120,6 +120,8 @@ def main(argv=None):
         # per-request latency + page-policy observability
         "ttft_p50_ms": round(st.get("ttft_p50", 0.0) * 1e3, 3),
         "ttft_p95_ms": round(st.get("ttft_p95", 0.0) * 1e3, 3),
+        "itl_p50_ms": round(st.get("itl_p50", 0.0) * 1e3, 3),
+        "itl_p95_ms": round(st.get("itl_p95", 0.0) * 1e3, 3),
         "queue_wait_p50_ms": round(st.get("queue_wait_p50", 0.0) * 1e3, 3),
         "queue_wait_p95_ms": round(st.get("queue_wait_p95", 0.0) * 1e3, 3),
         "prefix_hits": st.get("prefix_hits", 0),

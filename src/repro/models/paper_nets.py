@@ -2,11 +2,11 @@
 LSTMs (LSTM1: isolated digit recognition; LSTM2: continuous speech), and
 NCF (neural collaborative filtering, He et al.).
 
-These drive ``benchmarks/fig3.py`` — the reproduction of the paper's only
-performance table — comparing ``mode="opaque"`` (stock-XLA lowering) vs
-``mode="tapir"`` wall-time on CPU.  The LSTM cell is the paper's sweet
-spot: 8 isolated GEMM library calls vs one fused GEMM after the added-GEMM
-+ shared-input fusion passes."""
+They are the networks of the paper's only performance table, which
+compares ``mode="opaque"`` (stock-XLA lowering) with ``mode="tapir"``;
+``tests/test_paper_nets.py`` checks both modes agree.  The LSTM cell is
+the paper's sweet spot: 8 isolated GEMM library calls vs one fused GEMM
+after the added-GEMM + shared-input fusion passes."""
 from __future__ import annotations
 
 from dataclasses import dataclass

@@ -11,6 +11,7 @@ import numpy as np
 
 from repro.core import tapir
 from repro.dist import shard_act
+from repro.spans import span
 
 from . import layers as L
 from .base import BaseModel, ModelConfig, ParamSpec, register_family
@@ -483,24 +484,27 @@ class DenseLM(BaseModel):
         (scatter donation).  The page table rides in the cache pytree as
         data, so rebinding pages (shared prefixes, COW, parking) never
         changes the program."""
-        cfg = self.cfg
-        h = self._embed({"embed": sp["embed"]}, tokens)
-        pl = cache["k"][0].shape[1]
-        ptab = cache["ptab"]
-        max_len = ptab.shape[1] * pl
-        cos_t, sin_t = L.full_rope_table(max_len, cfg.hd,
-                                         fraction=self._rope_frac())
-        pos = cache["pos"]
-        bodies = self._slot_bodies()
-        blks = {kind: tapir.parallel_region(fn, name=f"slot_{kind}_block")
-                for kind, fn in bodies.items()}
-        for i, (kind, p) in enumerate(sp["layers"]):
-            h, ck, cv = blks[kind](p, h, cos_t, sin_t,
-                                   cache["k"][i], cache["v"][i], pos, ptab)
-            cache["k"][i], cache["v"][i] = ck, cv
-        head = tapir.parallel_region(self._slot_head_body, name="slot_head")
-        logits = head(sp["head"], h)
-        cache["pos"] = pos + 1
+        with span("model.decode", slots=tokens.shape[0]):
+            cfg = self.cfg
+            h = self._embed({"embed": sp["embed"]}, tokens)
+            pl = cache["k"][0].shape[1]
+            ptab = cache["ptab"]
+            max_len = ptab.shape[1] * pl
+            cos_t, sin_t = L.full_rope_table(max_len, cfg.hd,
+                                             fraction=self._rope_frac())
+            pos = cache["pos"]
+            bodies = self._slot_bodies()
+            blks = {kind: tapir.parallel_region(
+                        fn, name=f"slot_{kind}_block")
+                    for kind, fn in bodies.items()}
+            for i, (kind, p) in enumerate(sp["layers"]):
+                h, ck, cv = blks[kind](p, h, cos_t, sin_t, cache["k"][i],
+                                       cache["v"][i], pos, ptab)
+                cache["k"][i], cache["v"][i] = ck, cv
+            head = tapir.parallel_region(self._slot_head_body,
+                                         name="slot_head")
+            logits = head(sp["head"], h)
+            cache["pos"] = pos + 1
         return logits, cache
 
     def prefill_into_slot(self, sp, tokens, cache, slot: int, plen: int,
@@ -517,37 +521,42 @@ class DenseLM(BaseModel):
         plen-1, cache)."""
         cfg = self.cfg
         Sb = tokens.shape[1]
-        pl = int(cache["k"][0].shape[1])
-        row = np.asarray(cache["ptab"][slot])
-        pps = row.shape[0]
-        max_len = pps * pl
-        h = self._embed({"embed": sp["embed"]}, tokens)
-        cos_t, sin_t = L.full_rope_table(max(max_len, Sb), cfg.hd,
-                                         fraction=self._rope_frac())
-        p_abs = start + np.arange(Sb)
-        ok = p_abs < max_len
-        pidx = np.minimum(p_abs // pl, pps - 1)
-        phys = np.where(ok, row[pidx], 0).astype(np.int32)
-        off = np.where(ok, p_abs % pl, 0).astype(np.int32)
-        pos_clip = np.minimum(p_abs, cos_t.shape[0] - 1).astype(np.int32)
-        # device arrays: rebindable region inputs, not baked-in consts
-        pos_vec = jnp.asarray(pos_clip)
-        phys_vec = jnp.asarray(phys)
-        off_vec = jnp.asarray(off)
-        prow = jnp.asarray(row)
-        vlen = jnp.asarray(start + Sb, jnp.int32)
-        bodies = self._slot_prefill_bodies()
-        blks = {kind: tapir.parallel_region(fn, name=f"slot_{kind}_prefill")
-                for kind, fn in bodies.items()}
-        for i, (kind, p) in enumerate(sp["layers"]):
-            h, ck, cv = blks[kind](p, h, cos_t, sin_t,
-                                   cache["k"][i], cache["v"][i],
-                                   pos_vec, phys_vec, off_vec, prow, vlen)
-            cache["k"][i], cache["v"][i] = ck, cv
-        hrow = jax.lax.dynamic_slice_in_dim(h, plen - 1 - start, 1, axis=1)
-        head = tapir.parallel_region(self._slot_head_body, name="slot_head")
-        logits = head(sp["head"], hrow)
-        cache["pos"] = cache["pos"].at[slot].set(plen)
+        with span("model.prefill", tokens=Sb):
+            pl = int(cache["k"][0].shape[1])
+            row = np.asarray(cache["ptab"][slot])
+            pps = row.shape[0]
+            max_len = pps * pl
+            h = self._embed({"embed": sp["embed"]}, tokens)
+            cos_t, sin_t = L.full_rope_table(max(max_len, Sb), cfg.hd,
+                                             fraction=self._rope_frac())
+            p_abs = start + np.arange(Sb)
+            ok = p_abs < max_len
+            pidx = np.minimum(p_abs // pl, pps - 1)
+            phys = np.where(ok, row[pidx], 0).astype(np.int32)
+            off = np.where(ok, p_abs % pl, 0).astype(np.int32)
+            pos_clip = np.minimum(p_abs,
+                                  cos_t.shape[0] - 1).astype(np.int32)
+            # device arrays: rebindable region inputs, not baked-in consts
+            pos_vec = jnp.asarray(pos_clip)
+            phys_vec = jnp.asarray(phys)
+            off_vec = jnp.asarray(off)
+            prow = jnp.asarray(row)
+            vlen = jnp.asarray(start + Sb, jnp.int32)
+            bodies = self._slot_prefill_bodies()
+            blks = {kind: tapir.parallel_region(
+                        fn, name=f"slot_{kind}_prefill")
+                    for kind, fn in bodies.items()}
+            for i, (kind, p) in enumerate(sp["layers"]):
+                h, ck, cv = blks[kind](p, h, cos_t, sin_t,
+                                       cache["k"][i], cache["v"][i],
+                                       pos_vec, phys_vec, off_vec, prow, vlen)
+                cache["k"][i], cache["v"][i] = ck, cv
+            hrow = jax.lax.dynamic_slice_in_dim(h, plen - 1 - start, 1,
+                                                axis=1)
+            head = tapir.parallel_region(self._slot_head_body,
+                                         name="slot_head")
+            logits = head(sp["head"], hrow)
+            cache["pos"] = cache["pos"].at[slot].set(plen)
         return logits, cache
 
 

@@ -45,6 +45,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.spans import span
+
 TRASH_PAGE = 0
 
 
@@ -104,9 +106,10 @@ def copy_cache_pages(cache, src_ids, dst_ids) -> None:
     """Copy pages across every per-layer k/v pool, in place."""
     if not len(src_ids):
         return
-    for key in ("k", "v"):
-        for i, pool in enumerate(cache[key]):
-            cache[key][i] = copy_pages(pool, src_ids, dst_ids)
+    with span("serve.pages.copy", pages=len(src_ids)):
+        for key in ("k", "v"):
+            for i, pool in enumerate(cache[key]):
+                cache[key][i] = copy_pages(pool, src_ids, dst_ids)
 
 
 # -- prefix index ------------------------------------------------------------
@@ -191,17 +194,19 @@ class PagePool:
         k_max = len(prompt) // pl
         if k_max == 0:
             return 0, []
-        hashes = _chain_hashes(prompt, pl, k_max)
-        for k in range(k_max, 0, -1):
-            e = self.entries.get(hashes[k - 1])
-            if e is not None and np.array_equal(
-                    e.tokens, np.asarray(prompt[:k * pl], np.int32)):
-                return k, list(e.pages)
+        with span("serve.pages.lookup", pages=k_max):
+            hashes = _chain_hashes(prompt, pl, k_max)
+            for k in range(k_max, 0, -1):
+                e = self.entries.get(hashes[k - 1])
+                if e is not None and np.array_equal(
+                        e.tokens, np.asarray(prompt[:k * pl], np.int32)):
+                    return k, list(e.pages)
         return 0, []
 
     def bind(self, slot: int, prompt: np.ndarray, k: int) -> str:
         """Record slot -> entry binding (refcount +1); returns the hash."""
-        h = _chain_hashes(prompt, self.page_len, k)[-1]
+        with span("serve.pages.bind", pages=k):
+            h = _chain_hashes(prompt, self.page_len, k)[-1]
         e = self.entries[h]
         e.refs += 1
         self.clock += 1
@@ -229,14 +234,15 @@ class PagePool:
         k = self.publishable_pages(len(prompt))
         if k == 0:
             return 0
-        h = _chain_hashes(prompt, self.page_len, k)[-1]
-        if h in self.entries:
-            return 0
-        pages = self._alloc(k)
-        if pages is None:
-            return 0
-        src = [private_page(slot, j, self.pps) for j in range(k)]
-        copy_cache_pages(cache, src, pages)
+        with span("serve.pages.publish", pages=k):
+            h = _chain_hashes(prompt, self.page_len, k)[-1]
+            if h in self.entries:
+                return 0
+            pages = self._alloc(k)
+            if pages is None:
+                return 0
+            src = [private_page(slot, j, self.pps) for j in range(k)]
+            copy_cache_pages(cache, src, pages)
         self.clock += 1
         self.entries[h] = _Entry(
             pages=pages,
@@ -252,12 +258,13 @@ class PagePool:
         k = self.slot_bound[slot]
         n_used = -(-length // self.page_len)         # ceil
         priv = list(range(k, n_used))
-        pages = self._alloc(len(priv)) if priv else []
-        if pages is None:
-            return False
-        if priv:
-            src = [private_page(slot, j, self.pps) for j in priv]
-            copy_cache_pages(cache, src, pages)
+        with span("serve.pages.park", pages=len(priv)):
+            pages = self._alloc(len(priv)) if priv else []
+            if pages is None:
+                return False
+            if priv:
+                src = [private_page(slot, j, self.pps) for j in priv]
+                copy_cache_pages(cache, src, pages)
         self.parked[rid] = {"pages": pages, "first": k, "length": length,
                             "entry": self.slot_entry[slot],
                             "bound": k}
@@ -271,11 +278,12 @@ class PagePool:
         and free them; rebind its shared prefix.  Returns the park record
         (caller rebuilds the ptab row and pos)."""
         rec = self.parked.pop(rid)
-        if rec["pages"]:
-            dst = [private_page(slot, rec["first"] + i, self.pps)
-                   for i in range(len(rec["pages"]))]
-            copy_cache_pages(cache, rec["pages"], dst)
-            self.free.extend(rec["pages"])
+        with span("serve.pages.resume", pages=len(rec["pages"])):
+            if rec["pages"]:
+                dst = [private_page(slot, rec["first"] + i, self.pps)
+                       for i in range(len(rec["pages"]))]
+                copy_cache_pages(cache, rec["pages"], dst)
+                self.free.extend(rec["pages"])
         self.slot_entry[slot] = rec["entry"]
         self.slot_bound[slot] = rec["bound"]
         return rec

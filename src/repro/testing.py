@@ -1,9 +1,9 @@
-"""Multi-device subprocess harness, shared by tests AND benchmarks.
+"""Multi-device subprocess harness, shared by the tests and the examples.
 
 Mesh code needs more than one device, and
 ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` must be set BEFORE
 jax initializes — while the calling process must keep seeing ONE device
-(smoke tests and single-device benchmarks assume it).  So mesh bodies run
+(smoke tests assume it).  So mesh bodies run
 in a subprocess with a common preamble and hand their findings back as a
 ``result`` dict printed behind a ``RESULT::`` marker.
 

@@ -308,11 +308,31 @@ def test_ttft_and_queue_wait_reported():
     eng = ServingEngine(model, params, batch=1, max_len=32,
                         cfg=ServeConfig(target="cpu"))
     rng = np.random.default_rng(13)
-    eng.run([Request(rid=i,
-                     prompt=rng.integers(1, 100, size=4).astype(np.int32),
-                     max_new=2) for i in range(3)])
-    st = eng.last_stats
-    for k in ("ttft_p50", "ttft_p95", "queue_wait_p50", "queue_wait_p95"):
+    prompts = [rng.integers(1, 100, size=4).astype(np.int32)
+               for _ in range(3)]
+
+    def run(n):
+        reqs = [Request(rid=i, prompt=p, max_new=2)
+                for i, p in enumerate(prompts[:n])]
+        eng.run(reqs)
+        return reqs, eng.last_stats
+
+    run(3)                              # compiles: time the warm runs
+    reqs, st = run(3)
+    for k in ("ttft_p50", "ttft_p95", "queue_wait_p50", "queue_wait_p95",
+              "itl_p50", "itl_p95"):
         assert k in st and st[k] >= 0.0
-    # 3 requests through 1 slot: the later ones actually waited
-    assert st["queue_wait_p95"] >= st["queue_wait_p50"]
+    # 3 requests through 1 slot: the later ones actually waited, and
+    # each waited until its admission began, not until its first token
+    assert st["queue_wait_p95"] >= st["queue_wait_p50"] > 0.0
+    assert st["queue_wait_p50"] < st["ttft_p50"]
+    assert st["queue_wait_p95"] < st["ttft_p95"]
+    # one token time per output token; the one decode gap is the ITL
+    assert [len(r.token_times) for r in reqs] == [len(r.out) for r in reqs]
+    gaps = [r.token_times[1] - r.token_times[0] for r in reqs]
+    assert st["itl_p50"] == float(np.median(gaps))
+    # the first request admits at once: its wait (the slot cache's set-up,
+    # ~0.3 of it on the CPU) is a part of its time to first token, which
+    # holds its prefill
+    _, st = run(1)
+    assert st["queue_wait_p50"] < 0.75 * st["ttft_p50"]

@@ -1,0 +1,89 @@
+"""What the program writes for a profiler: region programs compiled under
+their region's name, the ``region_captures`` counter, and the
+``repro.tapir.capture`` span with where each program came from."""
+import glob
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from repro.core import tapir
+from repro.core.lowering import emit
+from repro.core.tapir import cache_stats, clear_cache
+
+
+def setup_function(_):
+    clear_cache()
+
+
+def _xw(rows=8):
+    x = jnp.asarray(np.random.default_rng(0).normal(size=(rows, 16)),
+                    jnp.float32)
+    w = jnp.asarray(np.random.default_rng(1).normal(size=(16, 32)),
+                    jnp.float32)
+    return x, w
+
+
+def slot_blk(x, w):
+    return tapir.linear(x, w, activation="gelu")
+
+
+def test_region_program_module_is_named_for_its_region():
+    x, w = _xw()
+    g = tapir.trace_region(slot_blk, x, w)
+    vals = {"a0": x, "a1": w}
+    for name, module in (("slot_blk", "jit_tapir_slot_blk"),
+                         ("slot_blk#1", "jit_tapir_slot_blk_1")):
+        g.name = name
+        jitted, names = tapir._positional_jit(emit(g, "cpu"), g)
+        text = jitted.lower(*[vals[n] for n in names]).as_text()
+        assert f"module @{module} " in text, text[:200]
+        assert "_positional" not in text.split("\n")[0]
+
+
+def test_region_captures_count_captures_not_replays():
+    x, w = _xw()
+    blk = tapir.parallel_region(slot_blk, name="slot_blk")
+    c0 = cache_stats()["region_captures"]
+    blk(x, w)
+    assert cache_stats()["region_captures"] == c0 + 1
+    for _ in range(3):
+        blk(x, w)                       # replays: no trace, no count
+    assert cache_stats()["region_captures"] == c0 + 1
+    blk(*_xw(rows=4))                   # a new shape captures again
+    assert cache_stats()["region_captures"] == c0 + 2
+    clear_cache()
+    assert cache_stats()["region_captures"] == 0
+
+
+def _capture_spans(path):
+    from jax.profiler import ProfileData
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            out.extend((e.start_ns, dict(e.stats)) for e in line.events
+                       if e.name == "repro.tapir.capture")
+    return [args for _, args in sorted(out, key=lambda t: t[0])]
+
+
+def test_capture_span_names_region_and_source(tmp_path):
+    x, w = _xw()
+
+    def twin(x, w):                     # same graph, another call site
+        return tapir.linear(x, w, activation="gelu")
+
+    blk = tapir.parallel_region(slot_blk, name="slot_blk")
+    other = tapir.parallel_region(twin, name="slot_blk")
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        blk(x, w)                       # compiled
+        blk(x, w)                       # replayed: no span
+        other(x, w)                     # captured, program from memory
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(tmp_path, "**", "*.xplane.pb"),
+                      recursive=True)
+    assert _capture_spans(path) == [
+        {"region": "slot_blk", "source": "compiled"},
+        {"region": "slot_blk", "source": "memory"}]

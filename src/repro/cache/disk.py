@@ -81,7 +81,10 @@ FORMAT_VERSION = 2
 #: 11: each region program's module is named for its region
 #: (``jit_tapir_<region>``); an older entry would put the old name back
 #: into a device trace.
-PIPELINE_VERSION = "repro-pipeline-11"
+#: 12: paged decode attention is a library op (``paged_attention``) whose
+#: impl the registry binds: the Pallas kernel reading pages in place, or
+#: the gathered-view composite that was a ``pyfunc`` node before.
+PIPELINE_VERSION = "repro-pipeline-12"
 
 
 def _versions() -> dict:

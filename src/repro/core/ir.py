@@ -52,9 +52,10 @@ def dtype_bytes(dtype: str) -> int:
 # ---------------------------------------------------------------------------
 
 #: Op vocabulary.  "Primitive" ops have pure-jnp lowerings.  "Library" ops
-#: (matmul, attention, linear_scan, conv2d) additionally have *exposed*
-#: implementations in ``repro.kernels`` whose epilogues the fusion pass may
-#: extend — the analogue of TapirXLA linking Tapir bitcode for Eigen routines.
+#: (matmul, attention, paged_attention, linear_scan, conv2d) additionally
+#: have *exposed* implementations in ``repro.kernels`` whose epilogues the
+#: fusion pass may extend — the analogue of TapirXLA linking Tapir bitcode
+#: for Eigen routines.
 PRIMITIVE_OPS = frozenset({
     "input", "const", "ew", "reduce", "reshape", "transpose", "broadcast",
     "slice", "concat", "split", "select", "iota", "convert", "softmax",
@@ -82,7 +83,8 @@ PRIMITIVE_OPS = frozenset({
     # input — MoE expert dispatch).
     "gather", "scatter",
 })
-LIBRARY_OPS = frozenset({"matmul", "attention", "linear_scan", "conv2d"})
+LIBRARY_OPS = frozenset({"matmul", "attention", "paged_attention",
+                         "linear_scan", "conv2d"})
 
 
 @dataclass
@@ -166,6 +168,11 @@ class Node:
             b, s, h, d = self.attrs["q_shape"]
             skv = self.attrs["kv_len"]
             return 4.0 * b * h * s * skv * d
+        if self.op == "paged_attention":
+            # the live length is data: the bound, every slot's whole view
+            b, s, h, d = self.attrs["q_shape"]
+            return 4.0 * b * h * s * self.attrs["pps"] \
+                * self.attrs["page_len"] * d
         if self.op == "linear_scan":
             return 8.0 * self.ttype.size
         if self.op in ("ew", "select", "convert", "softmax"):

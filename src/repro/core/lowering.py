@@ -8,13 +8,14 @@ registry (``core.schedule.IMPL_REGISTRY``) bound as the roofline argmin
 over that op's candidate lowerings.  No backend flag or shape threshold is
 re-derived here; the cost model already decided.
 
-* kernel impls (``flash_kernel`` / ``fused_kernel`` / ``kernel``) lower to
-  Pallas kernels (TPU target; interpret mode in tests) with fused epilogues
-  executed inside the kernel;
+* kernel impls (``flash_kernel`` / ``fused_kernel`` / ``paged_kernel`` /
+  ``kernel``) lower to Pallas kernels (TPU target; interpret mode in
+  tests) with fused epilogues executed inside the kernel;
 * jnp impls (``blockwise`` / ``chunked`` / ``materialized_*`` / ``einsum``
-  / ``ref``) lower to fused jnp composites — ``blockwise``/``chunked`` keep
-  their loop bodies under the ``tapir_vmem_body`` scope so ``launch.
-  hlo_cost`` can discount VMEM-resident traffic;
+  / ``ref`` / ``gathered``) lower to fused jnp composites —
+  ``blockwise``/``chunked`` keep their loop bodies under the
+  ``tapir_vmem_body`` scope so ``launch.hlo_cost`` can discount
+  VMEM-resident traffic;
 * ``"opaque"`` (sealed ops, early-heuristic mode) lowers the way stock XLA
   emitted Eigen calls: isolated per-op calls, per-expert loops for batched
   GEMMs, materialized attention scores, sequential scans.
@@ -174,6 +175,18 @@ def _lower_attention(node: Node, env: dict, backend: str) -> Any:
         from repro.kernels import flash_attention as fa
         y = fa.ref.attention_ref(q, k, v, causal=causal, bias=bias)
     return _apply_epilogue(y, node, env).astype(out_dtype)
+
+
+def _lower_paged_attention(node: Node, env: dict, backend: str) -> Any:
+    from repro.kernels import paged_attention as pa
+    q, ck, cv, ptab, lengths = (env[i] for i in node.inputs)
+    if node.schedule.impl == "paged_kernel":
+        return pa.ops.paged_attention(
+            q, ck, cv, ptab, lengths,
+            pages_per_block=node.schedule.tile["pages_per_block"],
+            interpret=backend != "tpu")
+    # "gathered", and the sealed/unscheduled forms of the same composite
+    return pa.ref.paged_attention_gathered(q, ck, cv, ptab, lengths)
 
 
 def _materialized_attention(q, k, v, causal, bias, grouped=False):
@@ -338,6 +351,8 @@ def _lower_node(node: Node, env: dict, inputs: dict, backend: str,
         return _lower_matmul(node, env, backend, bf16_partials)
     if op == "attention":
         return _lower_attention(node, env, backend)
+    if op == "paged_attention":
+        return _lower_paged_attention(node, env, backend)
     if op == "linear_scan":
         return _lower_linear_scan(node, env, backend)
     if op == "conv2d":

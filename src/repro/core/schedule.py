@@ -12,9 +12,10 @@ model over the optimized code.  This module is the TPU analogue:
   ``mesh:<axis>`` / ``grid`` / ``serial`` / ``vector``, picks MXU-aligned tile
   sizes that fit VMEM (strip-mining), serializes small tasks, and binds each
   library node's IMPLEMENTATION: every library op (matmul, attention,
-  linear_scan, conv2d) has a registry of candidate lowerings (``IMPL_REGISTRY``),
-  each carrying a roofline cost estimate (FLOPs + bytes moved + serial
-  dispatch steps, per shard) and availability constraints; the argmin is
+  paged_attention, linear_scan, conv2d) has a registry of candidate
+  lowerings (``IMPL_REGISTRY``), each carrying a roofline cost estimate
+  (FLOPs + bytes moved + serial dispatch steps, per shard) and
+  availability constraints; the argmin is
   bound to ``node.schedule.impl`` and ``core.lowering`` dispatches on that
   field alone — no ``backend == "tpu"`` flag or shape threshold re-derives
   the choice downstream.
@@ -37,6 +38,7 @@ from repro.kernels.flash_attention.ops import (attention_cost,
                                                kernel_unsupported)
 from repro.kernels.fused_matmul.ops import matmul_cost
 from repro.kernels.linear_scan.ops import SAFE_CHUNK, scan_cost
+from repro.kernels.paged_attention import ops as paged_ops
 
 
 @dataclass(frozen=True)
@@ -377,6 +379,36 @@ def attention_candidates(g: TaskGraph, node: Node, cm: CostModel,
     return out
 
 
+def paged_attention_candidates(g: TaskGraph, node: Node, cm: CostModel,
+                               backend: str, mesh_axes: Optional[dict] = None
+                               ) -> list[ImplCandidate]:
+    """Decode attention over the page pool, costed per shard at the bound
+    (every slot's whole view; the live length is data):
+
+    * ``paged_kernel`` — Pallas kernel reading the live pages in place
+                         (TPU, no mesh, shapes it takes)
+    * ``gathered``     — copy each slot's view out of the pool, then the
+                         masked composite attention over it"""
+    b, s, h, d = node.attrs["q_shape"]
+    hkv, page_len, pps = (node.attrs["kv_heads"], node.attrs["page_len"],
+                          node.attrs["pps"])
+    eb = dtype_bytes(g.nodes[node.inputs[1]].ttype.dtype)
+    shard = shard_factor(node, mesh_axes)
+
+    def roof(impl: str) -> float:
+        c = paged_ops.paged_attention_cost(b, s, h, hkv, d, page_len, pps,
+                                           eb, impl)
+        return (c["flops"] / cm.peak_flops + (
+            c["io_bytes"] + c["score_bytes"] * cm.score_passes_fused)
+            / cm.hbm_bw) / shard
+
+    why = _kernel_unavailable(backend, mesh_axes) or \
+        paged_ops.kernel_unsupported(b, s, h, hkv, d, page_len, pps, eb)
+    return [ImplCandidate("paged_kernel", None if why else
+                          roof("paged_kernel"), why),
+            ImplCandidate("gathered", roof("gathered"))]
+
+
 def matmul_candidates(g: TaskGraph, node: Node, cm: CostModel,
                       backend: str, mesh_axes: Optional[dict] = None
                       ) -> list[ImplCandidate]:
@@ -463,6 +495,7 @@ def conv2d_candidates(g: TaskGraph, node: Node, cm: CostModel,
 IMPL_REGISTRY: dict[str, Callable] = {
     "matmul": matmul_candidates,
     "attention": attention_candidates,
+    "paged_attention": paged_attention_candidates,
     "linear_scan": linear_scan_candidates,
     "conv2d": conv2d_candidates,
 }
@@ -621,6 +654,13 @@ def assign_schedules(g: TaskGraph, cm: CostModel, backend: str = "tpu",
             if node.attrs["gqa_impl"] == "repeat":
                 node.schedule.notes.append("gqa: repeat K/V (BLAS wins, "
                                            "copy cost amortized)")
+        elif node.op == "paged_attention":
+            a = node.attrs
+            eb = dtype_bytes(g.nodes[node.inputs[1]].ttype.dtype)
+            node.schedule.tile = {"pages_per_block":
+                                  paged_ops.pick_pages_per_block(
+                                      a["page_len"], a["kv_heads"],
+                                      a["q_shape"][-1], eb, a["pps"])}
         elif node.op == "linear_scan":
             # chunk the sequence; carry crosses chunks (the join).  Derived
             # from CostModel.vmem_bytes, capped at the numerically-exact

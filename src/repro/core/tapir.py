@@ -1403,6 +1403,18 @@ def _build_attention(g: TaskGraph, qi: int, ki: int, vi: int,
                  kv_heads=k_t.shape[2])
 
 
+def _build_paged_attention(g: TaskGraph, qi: int, ki: int, vi: int,
+                           ptabi: int, leni: int) -> int:
+    q_t, k_t = g.nodes[qi].ttype, g.nodes[ki].ttype
+    pps = g.nodes[ptabi].ttype.shape[-1]
+    out_t = TensorType(tuple(q_t.shape), q_t.dtype)
+    b, s, h, d = q_t.shape
+    return g.add("paged_attention", (qi, ki, vi, ptabi, leni), out_t,
+                 pdims=(0, 1, 2), rdims=(("kv", pps * k_t.shape[1]),),
+                 q_shape=(b, s, h, d), page_len=k_t.shape[1], pps=pps,
+                 kv_heads=k_t.shape[2])
+
+
 def _build_wkv_scan(g: TaskGraph, qi: int, ki: int, vi: int, wi: int,
                     ui: Optional[int]) -> int:
     q_t, v_t = g.nodes[qi].ttype, g.nodes[vi].ttype
@@ -1606,6 +1618,32 @@ def attention(q, k, v, causal: bool = False, bias=None):
         vi = g.add_input("v", _tt(v))
         bi = g.add_input("bias", _tt(bias)) if bias is not None else None
         g.set_outputs([_build_attention(g, qi, ki, vi, bi, causal)])
+
+    return _execute(sig, build, inputs)[0]
+
+
+def paged_attention(q, k_pool, v_pool, ptab, lengths):
+    """Decode attention over a page pool.  q: [B,1,H,D]; k_pool/v_pool:
+    [P,page_len,Hkv,D]; ptab: int32[B,pps], slot b's logical page j is
+    pool page ``ptab[b, j]``; lengths: int[B], keys at positions >=
+    lengths[b] are masked.  The registry binds the Pallas kernel that
+    reads the live pages in place, or the gathered-view composite."""
+    reg = _active_region()
+    if reg is not None:
+        out = _build_paged_attention(reg.g, reg.nid_of(q), reg.nid_of(k_pool),
+                                     reg.nid_of(v_pool), reg.nid_of(ptab),
+                                     reg.nid_of(lengths))
+        return reg.handle(out)
+
+    lengths = jnp.asarray(lengths, jnp.int32)
+    sig = ("paged_attention", q.shape, k_pool.shape, ptab.shape,
+           str(q.dtype), str(k_pool.dtype))
+    inputs = {"q": q, "k": k_pool, "v": v_pool, "ptab": ptab,
+              "lengths": lengths}
+
+    def build(g: TaskGraph):
+        ids = [g.add_input(n, _tt(v)) for n, v in inputs.items()]
+        g.set_outputs([_build_paged_attention(g, *ids)])
 
     return _execute(sig, build, inputs)[0]
 

@@ -9,6 +9,7 @@ Unlike an opaque library call, these implementations carry *open epilogue
 slots*: the fusion pass folds the calling context's elementwise tail into
 the kernel body (TapirXLA SIII, "Exposing parallel linear-algebra routines").
 """
-from . import flash_attention, fused_matmul, linear_scan
+from . import flash_attention, fused_matmul, linear_scan, paged_attention
 
-__all__ = ["flash_attention", "fused_matmul", "linear_scan"]
+__all__ = ["flash_attention", "fused_matmul", "linear_scan",
+           "paged_attention"]

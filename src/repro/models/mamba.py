@@ -21,7 +21,7 @@ from repro.kernels.linear_scan import ops as ls_ops
 
 from . import layers as L
 from .base import BaseModel, ModelConfig, ParamSpec, register_family
-from .transformer import DenseLM, _block_specs, _masked_decode_attention
+from .transformer import DenseLM, _block_specs
 
 CONV_K = 4
 

@@ -11,6 +11,7 @@ import numpy as np
 
 from repro.core import tapir
 from repro.dist import shard_act
+from repro.kernels.paged_attention.ref import masked_attention
 from repro.spans import span
 
 from . import layers as L
@@ -377,9 +378,10 @@ class DenseLM(BaseModel):
         pieces are graph values: RoPE rows gather at ``pos``, the write
         target resolves through the page table
         (``phys = ptab[s, pos // page_len]``), K/V scatter at
-        ``(phys, pos % page_len)``, and the masked attention reads the
-        per-slot view ``pool[ptab[s]]`` with ``pos + 1`` valid rows —
-        page indirection is data, so one program serves every binding.
+        ``(phys, pos % page_len)``, and the ``paged_attention`` library op
+        reads slot ``s``'s pages through ``ptab[s]`` with ``pos + 1``
+        valid rows — page indirection is data, so one program serves
+        every binding.
         On a mesh the ``shard_act`` constraints are captured as
         ``sharding`` annotations on the region nodes and replayed at
         lowering (heads over model; page dims unsharded so the donated
@@ -408,7 +410,7 @@ class DenseLM(BaseModel):
         cv = tapir.scatter(cv, (phys, off), v.reshape(B, Hkv, hd))
         ck = shard_act(ck, None, None, "kv", None)
         cv = shard_act(cv, None, None, "kv", None)
-        o = _paged_attention(q, ck, cv, ptab, pos + 1)
+        o = tapir.paged_attention(q, ck, cv, ptab, pos + 1)
         o = shard_act(o, "batch", None, "heads", None)
         # all-gather before wo so GSPMD never k-splits it (see _attn)
         o = shard_act(o.reshape(B, 1, H * hd), "batch", None, None)
@@ -575,36 +577,11 @@ def _decode_attention(q, ck, cv, valid_len):
     if any(tapir.is_traced(t) for t in (q, ck, cv, valid_len)):
         vl = valid_len if hasattr(valid_len, "shape") else jnp.asarray(
             valid_len, jnp.int32)
-        return tapir.lift(_masked_decode_attention, q, ck, cv, vl)
-    return _masked_decode_attention_jit(q, ck, cv, valid_len)
+        return tapir.lift(masked_attention, q, ck, cv, vl)
+    return _masked_attention_jit(q, ck, cv, valid_len)
 
 
-def _masked_decode_attention(q, ck, cv, valid_len):
-    """Composite masked attention over a static-length KV cache.
-    q: [B,S,H,hd], ck/cv: [B,maxlen,Hkv,hd]; positions >= valid_len masked.
-    ``valid_len`` is a scalar (one shared length) or a [B] vector (the
-    slot-paged cache: every slot has its own length — occupancy is data,
-    not shape)."""
-    B, S, H, hd = q.shape
-    maxlen, Hkv = ck.shape[1], ck.shape[2]
-    grp = H // Hkv
-    qg = q.reshape(B, S, Hkv, grp, hd)
-    s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, ck,
-                   preferred_element_type=jnp.float32) / np.sqrt(hd)
-    kpos = jnp.arange(maxlen)
-    vl = jnp.asarray(valid_len)
-    qpos = vl[..., None] - S + jnp.arange(S)       # [S] or [B,S]
-    mask = kpos <= qpos[..., None]                 # causal within cache
-    if mask.ndim == 2:
-        mask = mask[None]                          # shared length -> [1,S,k]
-    s = jnp.where(mask[:, None, None], s, jnp.finfo(jnp.float32).min)
-    p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(cv.dtype), cv,
-                   preferred_element_type=jnp.float32)
-    return o.reshape(B, S, H, hd).astype(q.dtype)
-
-
-_masked_decode_attention_jit = jax.jit(_masked_decode_attention)
+_masked_attention_jit = jax.jit(masked_attention)
 
 
 def _page_coords(pos, *, page_len):
@@ -620,31 +597,6 @@ def _page_coords_t(pos, *, page_len):
     return _page_coords(pos, page_len=page_len)
 
 
-def _paged_decode_attention(q, ck, cv, ptab, valid_len):
-    """Masked attention over a per-slot *view* of the page pool.
-    q: [B,S,H,hd]; ck/cv: [P,page_len,Hkv,hd] pools; ptab: [B,pps] page
-    table.  Gathering ``pool[ptab]`` materialises each slot's logical
-    [max_len] cache (shared prefix pages + private pages in one run) and
-    the result is bitwise-identical to the unpaged layout: each query
-    row's dot products, mask, and softmax depend only on its own keys,
-    never on which pages back them."""
-    B = q.shape[0]
-    pl, Hkv, hd = ck.shape[1], ck.shape[2], ck.shape[3]
-    pps = ptab.shape[-1]
-    vk = ck[ptab].reshape(B, pps * pl, Hkv, hd)
-    vv = cv[ptab].reshape(B, pps * pl, Hkv, hd)
-    return _masked_decode_attention(q, vk, vv, valid_len)
-
-
-def _paged_attention(q, ck, cv, ptab, valid_len):
-    """Traced-aware wrapper (see ``_decode_attention``)."""
-    if any(tapir.is_traced(t) for t in (q, ck, cv, ptab, valid_len)):
-        vl = valid_len if hasattr(valid_len, "shape") else jnp.asarray(
-            valid_len, jnp.int32)
-        return tapir.lift(_paged_decode_attention, q, ck, cv, ptab, vl)
-    return _paged_decode_attention_jit(q, ck, cv, ptab, valid_len)
-
-
 def _paged_prefill_attention(q, ck, cv, prow, valid_len):
     """Prefill attention for one slot through its page row.  q:
     [1,S,H,hd]; prow: [pps] page ids.  Reuses the masked decode kernel so
@@ -657,7 +609,7 @@ def _paged_prefill_attention(q, ck, cv, prow, valid_len):
     pps = prow.shape[-1]
     vk = ck[prow].reshape(1, pps * pl, Hkv, hd)
     vv = cv[prow].reshape(1, pps * pl, Hkv, hd)
-    return _masked_decode_attention(q, vk, vv, valid_len)
+    return masked_attention(q, vk, vv, valid_len)
 
 
 def _paged_prefill_attn(q, ck, cv, prow, valid_len):
@@ -668,5 +620,4 @@ def _paged_prefill_attn(q, ck, cv, prow, valid_len):
     return _paged_prefill_attention_jit(q, ck, cv, prow, valid_len)
 
 
-_paged_decode_attention_jit = jax.jit(_paged_decode_attention)
 _paged_prefill_attention_jit = jax.jit(_paged_prefill_attention)

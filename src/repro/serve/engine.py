@@ -47,7 +47,8 @@ inter-token gaps from each request's ``token_times``).
 **Spans.**  Under a profiler session the slot loop writes host spans
 (``repro.spans``): ``serve.tick`` per scheduler iteration, holding
 ``serve.admit`` (with its ``serve.pages.*`` operations and the blocking
-``serve.prefill``), the blocking ``serve.decode``, ``serve.release``,
+``serve.prefill``), the blocking ``serve.decode`` (``kv_pages``: the
+live KV pages the step reads, summed over slots), ``serve.release``,
 ``serve.preempt`` and ``serve.ckpt``.  The model's ``model.prefill`` /
 ``model.decode`` spans sit inside the two blocking ones and time the
 host's dispatch alone.
@@ -271,6 +272,7 @@ class _SlotRunState:
     tokens: np.ndarray           # [slots, 1] next feed token per slot
     pool: Any = None             # PagePool: shared-prefix / parking state
     ptab_host: Any = None        # np [slots, pps] mirror of cache["ptab"]
+    pos_host: Any = None         # np [slots] mirror of cache["pos"]
     pending: list = field(default_factory=list)  # indices awaiting a slot
     fed: list = field(default_factory=list)      # per-slot out tokens fed
     slot_seq: list = field(default_factory=list)  # admission order stamp
@@ -500,6 +502,7 @@ class ServingEngine:
             pool=pool,
             ptab_host=np.stack([identity_row(s, pool.pps)
                                 for s in range(self.slots)]),
+            pos_host=np.zeros(self.slots, np.int64),
             pending=list(range(len(requests))),
             fed=[0] * self.slots,
             slot_seq=[0] * self.slots,
@@ -572,6 +575,7 @@ class ServingEngine:
                                         self.max_len, self.cfg.page_len,
                                         self.cfg.shared_pages),
                 ptab_host=np.array(state["cache"]["ptab"]),
+                pos_host=np.array(state["cache"]["pos"], np.int64),
                 pending=list(meta["pending"]),
                 fed=list(meta["fed"]),
                 slot_seq=list(meta["slot_seq"]), seq=int(meta["seq"]),
@@ -718,6 +722,7 @@ class ServingEngine:
                 rs.ptab_host[s] = row
                 self._push_ptab(rs)
                 rs.cache["pos"] = rs.cache["pos"].at[s].set(rec["length"])
+                rs.pos_host[s] = rec["length"]
                 hp = rs.parked.pop(r.rid)
                 rs.tokens[s, 0] = hp["tok"]
                 rs.slot_steps[s] = hp["steps"]
@@ -761,6 +766,7 @@ class ServingEngine:
                 logits, rs.cache = model.prefill_into_slot(
                     sp, jnp.asarray(padded), rs.cache, s, plen, start=start)
                 tok = int(np.asarray(jnp.argmax(logits, -1))[0])
+            rs.pos_host[s] = plen
             if not replaying:
                 now = time.perf_counter()
                 r.out.append(tok)
@@ -794,6 +800,7 @@ class ServingEngine:
             rs.ptab_host[s] = identity_row(s, rs.pool.pps)
             self._push_ptab(rs)
             rs.cache["pos"] = rs.cache["pos"].at[s].set(0)
+            rs.pos_host[s] = 0
 
     def _slo_shed(self, requests, elig: list, rs: _SlotRunState,
                   ft: dict, wd) -> list:
@@ -834,7 +841,7 @@ class ServingEngine:
             return None
         victim = slot_req[s]
         with span("serve.preempt", rid=victim.rid):
-            length = int(np.asarray(rs.cache["pos"])[s])
+            length = int(rs.pos_host[s])
             arm = cfg.preempt_mode
             if arm == "auto":
                 arm = preempt_cost(
@@ -862,6 +869,7 @@ class ServingEngine:
             rs.ptab_host[s] = identity_row(s, pool.pps)
             self._push_ptab(rs)
             rs.cache["pos"] = rs.cache["pos"].at[s].set(0)
+            rs.pos_host[s] = 0
         return s
 
     def _slot_session(self, requests, max_steps: int, continuous: bool,
@@ -931,11 +939,16 @@ class ServingEngine:
                 t_step = time.perf_counter()
                 if delay:
                     time.sleep(delay)
-                with span("serve.decode", step=rs.step):
+                # pages the paged decode kernel walks: every slot's live
+                # ones, from the host's mirror of the lengths
+                live = np.clip(rs.pos_host + 1, 1, self.max_len)
+                kv_pages = int(np.sum(-(-live // rs.pool.page_len)))
+                with span("serve.decode", step=rs.step, kv_pages=kv_pages):
                     logits, rs.cache = model.decode_step_slots(
                         sp, jnp.asarray(rs.tokens), rs.cache)
                     nxt = np.asarray(
                         jnp.argmax(logits, -1).astype(jnp.int32))
+                rs.pos_host += 1
                 t_tok = time.perf_counter()
                 dt = t_tok - t_step
                 for s, r in enumerate(slot_req):

@@ -11,6 +11,9 @@
   ``pick_gqa_impl``
 * scan chunks / schedule metadata: SAFE_CHUNK cap, impl in
   ``signature()``, ``dump_schedule``/``tapir.explain`` observability.
+* paged decode attention: the Pallas kernel binds on the TPU target with
+  no mesh; off it, under a mesh, and for shapes it cannot take, the
+  gathered composite binds and the kernel shows ``n/a`` with its reason.
 """
 import jax
 import jax.numpy as jnp
@@ -235,8 +238,8 @@ def test_every_library_op_gets_an_impl_and_cost_table():
     mm = next(n for n in g.nodes.values() if n.op == "matmul")
     assert mm.schedule.impl == "einsum"   # no pallas GEMM off-TPU
     assert isinstance(mm.schedule.impl_costs["einsum"], float)
-    assert set(IMPL_REGISTRY) == {"matmul", "attention", "linear_scan",
-                                  "conv2d"}
+    assert set(IMPL_REGISTRY) == {"matmul", "attention", "paged_attention",
+                                  "linear_scan", "conv2d"}
 
 
 # ---------------------------------------------------------------------------
@@ -281,3 +284,113 @@ def test_cost_model_follows_the_device_kind():
     assert (v5e.peak_flops, v5e.hbm_bw) == (197e12, 819e9)
     with pytest.raises(ValueError, match="no cost model"):
         cost_model_for("TPU v99")       # its peaks would otherwise be assumed
+
+
+# ---------------------------------------------------------------------------
+# paged decode attention: the Pallas kernel reading pages in place vs the
+# gathered-view composite
+# ---------------------------------------------------------------------------
+
+
+def _paged_graph(backend="cpu", cm=CPU_COST_MODEL, force=None, mesh_axes=None,
+                 hd=128, slots=4, pps=4, page_len=64):
+    """Trace one paged_attention node (the serving decode shape: 16/2
+    heads) and schedule it for ``backend``, optionally under a mesh."""
+    from repro.core.passes import optimize_graph
+    from repro.core.schedule import assign_schedules
+    q = jnp.zeros((slots, 1, 16, hd), jnp.bfloat16)
+    pool = jnp.zeros((slots * pps + 1, page_len, 2, hd), jnp.bfloat16)
+    ptab = jnp.zeros((slots, pps), jnp.int32)
+    lens = jnp.ones((slots,), jnp.int32)
+    with use(TapirConfig(mode="tapir", backend=backend, cost_model=cm)):
+        g = tapir.capture_region(tapir.paged_attention, q, pool, pool, ptab,
+                                 lens)
+    optimize_graph(g, cm)
+    assign_schedules(g, cm, backend=backend, mesh_axes=mesh_axes,
+                     force_impl=force)
+    return g
+
+
+def _paged_node(g):
+    return next(n for n in g.nodes.values() if n.op == "paged_attention")
+
+
+def test_paged_kernel_bound_on_tpu_without_mesh():
+    n = _paged_node(_paged_graph("tpu", TPU_CM))
+    assert n.schedule.impl == "paged_kernel"
+    costs = n.schedule.impl_costs
+    assert costs["paged_kernel"] < costs["gathered"]
+    # the view's read against its read, write and re-read
+    assert costs["gathered"] > 2.5 * costs["paged_kernel"]
+    assert n.schedule.tile == {"pages_per_block": 4}
+
+
+@pytest.mark.parametrize("backend,mesh_axes,hd,why", [
+    ("cpu", None, 128, "pallas kernel needs the TPU target"),
+    ("tpu", {"data": 1, "model": 4}, 128, "GSPMD cannot partition"),
+    ("tpu", None, 64, "head size 64"),
+])
+def test_paged_kernel_na_binds_gathered(backend, mesh_axes, hd, why):
+    cm = TPU_CM if backend == "tpu" else CPU_COST_MODEL
+    n = _paged_node(_paged_graph(backend, cm, mesh_axes=mesh_axes, hd=hd))
+    assert n.schedule.impl == "gathered"
+    na = n.schedule.impl_costs["paged_kernel"]
+    assert isinstance(na, str) and na.startswith("n/a") and why in na
+    assert isinstance(n.schedule.impl_costs["gathered"], float)
+
+
+def test_paged_force_impl_changes_lowered_jaxpr_and_signature():
+    from repro.core.lowering import emit
+
+    def jaxpr_of(g):
+        args = {n: jnp.zeros(tuple(g.nodes[nid].ttype.shape),
+                             g.nodes[nid].ttype.dtype)
+                for n, nid in g.inputs}
+        return str(jax.make_jaxpr(lambda a: emit(g, "tpu")(a))(args))
+
+    g_k = _paged_graph("tpu", TPU_CM,
+                       force=(("paged_attention", "paged_kernel"),))
+    g_g = _paged_graph("tpu", TPU_CM,
+                       force=(("paged_attention", "gathered"),))
+    assert _paged_node(g_g).schedule.impl == "gathered"
+    j_k, j_g = jaxpr_of(g_k), jaxpr_of(g_g)
+    assert "pallas_call" in j_k and "gather" not in j_k
+    assert "pallas_call" not in j_g and "gather" in j_g
+    # the op and its bound impl enter the program's identity
+    assert g_k.signature() != g_g.signature()
+    assert "paged_attention" in str(g_k.signature())
+    with pytest.raises(ValueError, match="unavailable"):
+        _paged_graph("cpu", force=(("paged_attention", "paged_kernel"),))
+
+
+def test_paged_node_in_explain():
+    txt = tapir.explain(_paged_graph("tpu", TPU_CM))
+    assert "paged_attention" in txt and "impl=paged_kernel" in txt
+    assert "paged_kernel=" in txt and "gathered=" in txt
+    assert "pages_per_block" in txt
+
+
+def test_slot_decode_region_binds_paged_op():
+    """The model's slot decode block captures decode attention as one
+    ``paged_attention`` library node, which the CPU target binds to the
+    gathered composite."""
+    import dataclasses
+
+    import repro.configs as C
+    from repro.models.base import get_model
+    cfg = dataclasses.replace(C.get_smoke("qwen2_5_3b"),
+                              compute_dtype="float32")
+    model = get_model(cfg)
+    sp = model.slot_params(model.init_params(jax.random.PRNGKey(0)))
+    cache = model.init_slot_cache(2, 128)
+    with use(_cfg()):
+        logits, _ = model.decode_step_slots(
+            sp, jnp.ones((2, 1), jnp.int32), cache)
+    graphs = [g for g in tapir.cached_graphs().values()
+              if g.name.startswith("slot_dense_block")]
+    assert graphs
+    for g in graphs:
+        ops = [n.op for n in g.nodes.values()]
+        assert ops.count("paged_attention") == 1
+        assert _paged_node(g).schedule.impl == "gathered"
+    assert np.isfinite(np.asarray(logits)).all()

@@ -87,3 +87,64 @@ def test_capture_span_names_region_and_source(tmp_path):
     assert _capture_spans(path) == [
         {"region": "slot_blk", "source": "compiled"},
         {"region": "slot_blk", "source": "memory"}]
+
+
+class _PosRecorder:
+    """The model, recording each decode step's device lengths first."""
+
+    def __init__(self, model):
+        self._model = model
+        self.pos = []
+
+    def __getattr__(self, name):
+        return getattr(self._model, name)
+
+    def decode_step_slots(self, sp, tokens, cache):
+        self.pos.append(np.asarray(cache["pos"]).copy())
+        return self._model.decode_step_slots(sp, tokens, cache)
+
+
+def _decode_span_args(path):
+    from jax.profiler import ProfileData
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            out.extend((e.start_ns, dict(e.stats)) for e in line.events
+                       if e.name == "repro.serve.decode")
+    return [args for _, args in sorted(out, key=lambda t: t[0])]
+
+
+def test_decode_span_counts_live_kv_pages(tmp_path):
+    """``serve.decode``'s ``kv_pages`` is the live pages of every slot,
+    from the host's mirror of the lengths: it equals what the device's
+    lengths give at every step, through admission, release and a
+    preemption that parks a request and resumes it."""
+    import repro.configs as C
+    from repro.models.base import get_model
+    from repro.serve import Request, ServeConfig, ServingEngine
+    model = get_model(C.get_smoke("qwen2_5_3b"))
+    params = model.init_params(jax.random.PRNGKey(0))
+    rec = _PosRecorder(model)
+    max_len, page_len = 64, 8
+    eng = ServingEngine(rec, params, batch=2, max_len=max_len,
+                        cfg=ServeConfig(target="cpu", page_len=page_len,
+                                        preempt_mode="park"))
+    rng = np.random.default_rng(0)
+    reqs = [Request(rid=i, prompt=rng.integers(1, 100, size=n)
+                    .astype(np.int32), max_new=m, priority=p,
+                    arrival_step=a)
+            for i, (n, m, p, a) in enumerate([(7, 14, 0, 0), (17, 5, 0, 0),
+                                              (3, 4, 5, 2)])]
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.run(reqs)
+    finally:
+        jax.profiler.stop_trace()
+    assert eng.last_stats["parked"] == 1
+    path, = glob.glob(os.path.join(tmp_path, "**", "*.xplane.pb"),
+                      recursive=True)
+    got = [a["kv_pages"] for a in _decode_span_args(path)]
+    want = [int(np.sum(-(-np.clip(p + 1, 1, max_len) // page_len)))
+            for p in rec.pos]
+    assert got == want and len(got) == eng.last_stats["decode_steps"]
+    assert min(got) >= 2 and max(got) < 2 * max_len // page_len

@@ -26,6 +26,7 @@ from repro.core.tapir import TapirConfig, use
 from repro.kernels.flash_attention import ops as fa_ops
 from repro.kernels.fused_matmul import ops as fm_ops
 from repro.kernels.linear_scan import ops as ls_ops
+from repro.kernels.paged_attention import ops as pa_ops
 
 V5E = cost_model_for("TPU v5 lite")
 BF16 = jnp.bfloat16
@@ -106,6 +107,84 @@ def test_linear_scan_compiles(one_chip):
         return ls_ops.linear_scan(q, k, v, w, u=u, chunk=ls_ops.SAFE_CHUNK)
 
     _compile(f, t, t, t, t, u)
+
+
+def test_paged_attention_compiles_in_place(one_chip):
+    """The decode region's pattern at published widths: 16 slots of 64
+    pages of 64 positions, 16/2 heads of 128, bf16.  The step writes one
+    row into each pool and the kernel reads the pools through the page
+    table; the pools are neither copied nor gathered on the way (the
+    kernel's head-interleaved view is a bitcast of the pool)."""
+    import re
+    slots, pps, page_len, hq, hkv, d = 16, 64, 64, 16, 2, 128
+    n_pages = slots * pps + 1
+    pool = _sds((n_pages, page_len, hkv, d), BF16, one_chip)
+    i32 = lambda *s: _sds(s, jnp.int32, one_chip)   # noqa: E731
+
+    def step(q, ck, cv, ptab, lens, knew, phys, off):
+        ck = ck.at[phys, off].set(knew)
+        cv = cv.at[phys, off].set(knew)
+        return pa_ops.paged_attention(q, ck, cv, ptab, lens), ck, cv
+
+    compiled = jax.jit(step, donate_argnums=(1, 2)).lower(
+        _sds((slots, 1, hq, d), BF16, one_chip), pool, pool,
+        i32(slots, pps), i32(slots), _sds((slots, hkv, d), BF16, one_chip),
+        i32(slots), i32(slots)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert " gather(" not in text
+    pool_hlo = f"bf16[{n_pages},{page_len},{hkv},{d}]"
+    assert not re.search(rf"= {re.escape(pool_hlo)}\S* copy\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def test_slot_decode_binds_paged_kernel_on_v5e(one_chip):
+    """One slot-decode step of a layer with qwen2_5_3b's attention (16/2
+    heads of 128; 16 slots x 4096 in 64-position pages), cache donated as
+    the engine's region programs donate it: the registry binds the paged
+    kernel, and the program neither copies nor gathers a page pool."""
+    import dataclasses
+    import re
+
+    import repro.configs as C
+    from repro.models import layers as L
+    from repro.models.base import get_model
+
+    cfg = dataclasses.replace(
+        C.get_smoke("qwen2_5_3b"), d_model=2048, n_heads=16, n_kv_heads=2,
+        head_dim=128, d_ff=512, vocab=512, n_layers=1,
+        param_dtype="bfloat16", compute_dtype="bfloat16")
+    model = get_model(cfg)
+    slots, max_len = 16, 4096
+
+    def layers_and_head():
+        sp = model.slot_params(model.init_params(jax.random.PRNGKey(0)))
+        return {**{k: v for k, v in sp.items() if k != "layers"},
+                "layers": [d for _, d in sp["layers"]]}
+
+    def step(lh, tokens, cache):
+        sp = {**lh, "layers": [("dense", d) for d in lh["layers"]]}
+        return model.decode_step_slots(sp, tokens, cache)
+
+    cache = model.slot_cache_specs(slots, max_len)
+    L.full_rope_table(max_len, cfg.hd, fraction=model._rope_frac())
+    sds = lambda tree: jax.tree_util.tree_map(   # noqa: E731
+        lambda v: _sds(v.shape, v.dtype, one_chip), tree)
+    tapir.clear_cache()
+    with use(TapirConfig(mode="tapir", backend="tpu", cost_model=V5E)):
+        text = jax.jit(step, donate_argnums=(2,)).lower(
+            sds(jax.eval_shape(layers_and_head)),
+            _sds((slots, 1), jnp.int32, one_chip), sds(cache)
+        ).compile().as_text()
+        report = tapir.explain()
+    tapir.clear_cache()
+    assert "paged_attention" in report and "impl=paged_kernel" in report
+    assert "paged_attention" in text and "tpu_custom_call" in text
+    pool = cache["k"][0]
+    pool_hlo = "bf16[" + ",".join(map(str, pool.shape)) + "]"
+    assert not re.search(rf"= {re.escape(pool_hlo)}\S* (copy|gather)\(",
+                         text)
+    assert not re.search(r"= bf16\[16,64,64,2,128\]\S* gather\(", text)
 
 
 def test_fused_kernel_node_reverse_mode_compiles(one_chip):

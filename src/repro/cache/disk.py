@@ -84,7 +84,10 @@ FORMAT_VERSION = 2
 #: 12: paged decode attention is a library op (``paged_attention``) whose
 #: impl the registry binds: the Pallas kernel reading pages in place, or
 #: the gathered-view composite that was a ``pyfunc`` node before.
-PIPELINE_VERSION = "repro-pipeline-12"
+#: 13: the one-position SSM state update is a library op
+#: (``ssm_state_step``, two output nodes lowered once) whose impl the
+#: registry binds: the in-place Pallas kernel or the jnp composite.
+PIPELINE_VERSION = "repro-pipeline-13"
 
 
 def _versions() -> dict:

@@ -52,10 +52,12 @@ def dtype_bytes(dtype: str) -> int:
 # ---------------------------------------------------------------------------
 
 #: Op vocabulary.  "Primitive" ops have pure-jnp lowerings.  "Library" ops
-#: (matmul, attention, paged_attention, linear_scan, conv2d) additionally
-#: have *exposed* implementations in ``repro.kernels`` whose epilogues the
-#: fusion pass may extend — the analogue of TapirXLA linking Tapir bitcode
-#: for Eigen routines.
+#: (matmul, attention, paged_attention, linear_scan, ssm_state_step, conv2d)
+#: additionally have *exposed* implementations in ``repro.kernels`` whose
+#: epilogues the fusion pass may extend — the analogue of TapirXLA linking
+#: Tapir bitcode for Eigen routines.  ``ssm_state_step`` has two outputs
+#: (y, the updated state rows): it is recorded as two nodes over the same
+#: operands, told apart by ``out``, and lowered once.
 PRIMITIVE_OPS = frozenset({
     "input", "const", "ew", "reduce", "reshape", "transpose", "broadcast",
     "slice", "concat", "split", "select", "iota", "convert", "softmax",
@@ -84,7 +86,7 @@ PRIMITIVE_OPS = frozenset({
     "gather", "scatter",
 })
 LIBRARY_OPS = frozenset({"matmul", "attention", "paged_attention",
-                         "linear_scan", "conv2d"})
+                         "linear_scan", "ssm_state_step", "conv2d"})
 
 
 @dataclass
@@ -175,6 +177,9 @@ class Node:
                 * self.attrs["page_len"] * d
         if self.op == "linear_scan":
             return 8.0 * self.ttype.size
+        if self.op == "ssm_state_step":
+            s, h, p, n = self.attrs["dims"]
+            return 5.0 * s * h * p * n
         if self.op in ("ew", "select", "convert", "softmax"):
             return float(self.ttype.size) * (4.0 if self.op == "softmax" else 1.0)
         if self.op == "reduce":
@@ -494,6 +499,10 @@ class TaskGraph:
                     f"{name}={fmt(v)}" for name, v in ranked))
             if n.schedule.tile:
                 lines.append(f"      tile: {n.schedule.tile}")
+            if "experts_held" in n.attrs:
+                lo, hi, routed = n.attrs["experts_held"]
+                lines.append(f"      experts held: {lo}..{hi - 1} of "
+                             f"{routed} routed")
             for note in n.schedule.notes:
                 lines.append(f"      note: {note}")
         if n_lib == 0:

@@ -12,7 +12,7 @@ re-derived here; the cost model already decided.
   ``kernel``) lower to Pallas kernels (TPU target; interpret mode in
   tests) with fused epilogues executed inside the kernel;
 * jnp impls (``blockwise`` / ``chunked`` / ``materialized_*`` / ``einsum``
-  / ``ref`` / ``gathered``) lower to fused jnp composites —
+  / ``ref`` / ``gathered`` / ``composite``) lower to fused jnp composites —
   ``blockwise``/``chunked`` keep their loop bodies under the
   ``tapir_vmem_body`` scope so ``launch.hlo_cost`` can discount
   VMEM-resident traffic;
@@ -189,6 +189,23 @@ def _lower_paged_attention(node: Node, env: dict, backend: str) -> Any:
     return pa.ref.paged_attention_gathered(q, ck, cv, ptab, lengths)
 
 
+def _lower_ssm_state_step(node: Node, env: dict, backend: str) -> Any:
+    """Both output nodes lower through ONE call, memoized in ``env``
+    under the operands' nids: the kernel (or composite) runs once."""
+    key = ("ssm_state_step",) + tuple(node.inputs)
+    res = env.get(key)
+    if res is None:
+        from repro.kernels import ssm_state_step as ss
+        args = [env[i] for i in node.inputs]
+        if node.schedule.impl == "kernel":
+            res = ss.ops.ssm_state_step(*args, interpret=backend != "tpu")
+        else:
+            # "composite", and the sealed/unscheduled forms of the same
+            res = ss.ref.ssm_state_step_ref(*args)
+        env[key] = res
+    return res[node.attrs["out"]]
+
+
 def _materialized_attention(q, k, v, causal, bias, grouped=False):
     hq, hkv = q.shape[2], k.shape[2]
     scale = 1.0 / np.sqrt(q.shape[-1])
@@ -355,6 +372,8 @@ def _lower_node(node: Node, env: dict, inputs: dict, backend: str,
         return _lower_paged_attention(node, env, backend)
     if op == "linear_scan":
         return _lower_linear_scan(node, env, backend)
+    if op == "ssm_state_step":
+        return _lower_ssm_state_step(node, env, backend)
     if op == "conv2d":
         return _lower_conv2d(node, env, backend)
     raise NotImplementedError(op)

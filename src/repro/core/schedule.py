@@ -12,12 +12,11 @@ model over the optimized code.  This module is the TPU analogue:
   ``mesh:<axis>`` / ``grid`` / ``serial`` / ``vector``, picks MXU-aligned tile
   sizes that fit VMEM (strip-mining), serializes small tasks, and binds each
   library node's IMPLEMENTATION: every library op (matmul, attention,
-  paged_attention, linear_scan, conv2d) has a registry of candidate
-  lowerings (``IMPL_REGISTRY``), each carrying a roofline cost estimate
-  (FLOPs + bytes moved + serial dispatch steps, per shard) and
-  availability constraints; the argmin is
-  bound to ``node.schedule.impl`` and ``core.lowering`` dispatches on that
-  field alone — no ``backend == "tpu"`` flag or shape threshold re-derives
+  paged_attention, linear_scan, ssm_state_step, conv2d) has a registry of
+  candidate lowerings (``IMPL_REGISTRY``), each carrying a roofline cost
+  estimate (FLOPs + bytes moved + serial dispatch steps, per shard) and
+  availability constraints; the argmin is bound to ``node.schedule.impl``
+  and ``core.lowering`` dispatches on that field alone — no ``backend == "tpu"`` flag or shape threshold re-derives
   the choice downstream.
 
 In ``mode="opaque"`` the pipeline instead calls ``assign_early_heuristics``
@@ -39,6 +38,7 @@ from repro.kernels.flash_attention.ops import (attention_cost,
 from repro.kernels.fused_matmul.ops import matmul_cost
 from repro.kernels.linear_scan.ops import SAFE_CHUNK, scan_cost
 from repro.kernels.paged_attention import ops as paged_ops
+from repro.kernels.ssm_state_step import ops as ssm_ops
 
 
 @dataclass(frozen=True)
@@ -473,6 +473,29 @@ def linear_scan_candidates(g: TaskGraph, node: Node, cm: CostModel,
     return out
 
 
+def ssm_state_step_candidates(g: TaskGraph, node: Node, cm: CostModel,
+                              backend: str, mesh_axes: Optional[dict] = None
+                              ) -> list[ImplCandidate]:
+    """One decode position of an SSM over per-slot state rows, costed per
+    shard:
+
+    * ``kernel``    — Pallas kernel updating the rows in place, one pass
+                      over the state (TPU, no mesh, shapes it takes)
+    * ``composite`` — the jnp update: XLA walks the state four times"""
+    s, h, p, n = node.attrs["dims"]
+    shard = shard_factor(node, mesh_axes)
+
+    def roof(impl: str) -> float:
+        c = ssm_ops.ssm_state_step_cost(s, h, p, n, impl)
+        return (c["flops"] / cm.peak_flops + c["io_bytes"] / cm.hbm_bw) \
+            / shard
+
+    why = _kernel_unavailable(backend, mesh_axes) or \
+        ssm_ops.kernel_unsupported(s, h, p, n)
+    return [ImplCandidate("kernel", None if why else roof("kernel"), why),
+            ImplCandidate("composite", roof("composite"))]
+
+
 def conv2d_candidates(g: TaskGraph, node: Node, cm: CostModel,
                       backend: str, mesh_axes: Optional[dict] = None
                       ) -> list[ImplCandidate]:
@@ -497,6 +520,7 @@ IMPL_REGISTRY: dict[str, Callable] = {
     "attention": attention_candidates,
     "paged_attention": paged_attention_candidates,
     "linear_scan": linear_scan_candidates,
+    "ssm_state_step": ssm_state_step_candidates,
     "conv2d": conv2d_candidates,
 }
 

@@ -1415,6 +1415,23 @@ def _build_paged_attention(g: TaskGraph, qi: int, ki: int, vi: int,
                  kv_heads=k_t.shape[2])
 
 
+def _build_ssm_state_step(g: TaskGraph, hi: int, ai: int, ui: int, bi: int,
+                          ci: int) -> tuple[int, int]:
+    """Two nodes over the same operands: ``out=0`` is y [S,H,P] f32,
+    ``out=1`` the state rows, written in place (the node donates ``h``,
+    and orders after the y node, which reads it)."""
+    h_t, u_t = g.nodes[hi].ttype, g.nodes[ui].ttype
+    s, h, p = u_t.shape
+    n = h_t.shape[2]
+    ins = (hi, ai, ui, bi, ci)
+    kw = dict(rdims=(("state", n),), dims=(s, h, p, n))
+    y = g.add("ssm_state_step", ins, TensorType((s, h, p), "float32"),
+              pdims=(0, 1, 2), out=0, **kw)
+    hn = g.add("ssm_state_step", ins, h_t, pdims=(0, 1, 2, 3), donates=hi,
+               out=1, **kw)
+    return y, hn
+
+
 def _build_wkv_scan(g: TaskGraph, qi: int, ki: int, vi: int, wi: int,
                     ui: Optional[int]) -> int:
     q_t, v_t = g.nodes[qi].ttype, g.nodes[vi].ttype
@@ -1426,20 +1443,23 @@ def _build_wkv_scan(g: TaskGraph, qi: int, ki: int, vi: int, wi: int,
 
 
 def _build_expert_mlp(g: TaskGraph, xi: int, wgi: int, wui: int, wdi: int,
-                      activation: str) -> int:
+                      activation: str, held: Optional[tuple] = None) -> int:
     E, C, d = g.nodes[xi].ttype.shape
     dt = g.nodes[xi].ttype.dtype
     f = g.nodes[wgi].ttype.shape[-1]
     hid_t = TensorType((E, C, f), dt)
+    # ``held``: (lo, hi, routed) — which of the router's experts these
+    # GEMMs hold; recorded on them for ``explain``
+    kw = {} if held is None else {"experts_held": tuple(held)}
     mg = g.add("matmul", (xi, wgi), hid_t, pdims=(0, 1, 2),
-               rdims=(("k", d),), k=d)
+               rdims=(("k", d),), k=d, **kw)
     mu = g.add("matmul", (xi, wui), hid_t, pdims=(0, 1, 2),
-               rdims=(("k", d),), k=d)
+               rdims=(("k", d),), k=d, **kw)
     act = g.add("ew", (mg,), hid_t, pdims=(0, 1, 2), fn=activation)
     prod = g.add("ew", (act, mu), hid_t, pdims=(0, 1, 2), fn="mul")
     out_t = TensorType((E, C, d), dt)
     return g.add("matmul", (prod, wdi), out_t, pdims=(0, 1, 2),
-                 rdims=(("k", f),), k=f)
+                 rdims=(("k", f),), k=f, **kw)
 
 
 def _build_lstm_step(g: TaskGraph, xi: int, hi: int, ci: int, Wi: int,
@@ -1648,6 +1668,30 @@ def paged_attention(q, k_pool, v_pool, ptab, lengths):
     return _execute(sig, build, inputs)[0]
 
 
+def ssm_state_step(h, a, u, bm, cm):
+    """One-position SSM state update over packed state rows (decode).
+    h: f32[R, H/g, N, g*P] (``kernels.ssm_state_step.ref``'s layout);
+    a: f32[S, H] decay; u: f32[S, H, P], dt * x; bm, cm: f32[S, N].  For
+    rows s < S: ``h <- a h + u (x) B``, ``y = C . h``; rows past S are left
+    as they are.  Returns (y f32[S, H, P], h).  The registry binds the
+    Pallas kernel that updates the rows in place, or the jnp composite."""
+    reg = _active_region()
+    if reg is not None:
+        y, hn = _build_ssm_state_step(reg.g, *(reg.nid_of(t)
+                                               for t in (h, a, u, bm, cm)))
+        return reg.handle(y), reg.handle(hn)
+
+    sig = ("ssm_state_step", h.shape, u.shape, bm.shape)
+    inputs = {"h": h, "a": a, "u": u, "bm": bm, "cm": cm}
+
+    def build(g: TaskGraph):
+        ids = [g.add_input(n, _tt(v)) for n, v in inputs.items()]
+        g.set_outputs(list(_build_ssm_state_step(g, *ids)))
+
+    y, hn = _execute(sig, build, inputs)
+    return y, hn
+
+
 def wkv_scan(q, k, v, w, u=None):
     """Gated linear-attention scan:  S_t = diag(w_t) S_{t-1} + k_t^T v_t,
     o_t = q_t S_t (+ u * (q_t . k_t) v_t bonus when u given — RWKV6).
@@ -1673,19 +1717,21 @@ def wkv_scan(q, k, v, w, u=None):
     return _execute(sig, build, inputs)[0]
 
 
-def expert_mlp(xe, w_gate, w_up, w_down, activation: str = "silu"):
+def expert_mlp(xe, w_gate, w_up, w_down, activation: str = "silu",
+               held: Optional[tuple] = None):
     """Batched expert FFN: xe [E,C,d] x w [E,d,f].  In opaque mode the
     batched GEMMs lower to per-expert library calls; in tapir mode a single
-    grouped einsum with fused epilogues."""
+    grouped einsum with fused epilogues.  ``held`` (lo, hi, routed) names
+    which of the router's experts these are, for ``explain``."""
     reg = _active_region()
     if reg is not None:
         out = _build_expert_mlp(reg.g, reg.nid_of(xe), reg.nid_of(w_gate),
                                 reg.nid_of(w_up), reg.nid_of(w_down),
-                                activation)
+                                activation, held)
         return reg.handle(out)
 
     sig = ("expert_mlp", xe.shape, str(xe.dtype), w_gate.shape, w_down.shape,
-           activation)
+           activation) + (() if held is None else (held,))
     inputs = {"x": xe, "wg": w_gate, "wu": w_up, "wd": w_down}
 
     def build(g: TaskGraph):
@@ -1693,7 +1739,8 @@ def expert_mlp(xe, w_gate, w_up, w_down, activation: str = "silu"):
         wg = g.add_input("wg", _tt(w_gate))
         wu = g.add_input("wu", _tt(w_up))
         wd = g.add_input("wd", _tt(w_down))
-        g.set_outputs([_build_expert_mlp(g, xi, wg, wu, wd, activation)])
+        g.set_outputs([_build_expert_mlp(g, xi, wg, wu, wd, activation,
+                                         held)])
 
     return _execute(sig, build, inputs)[0]
 

@@ -61,11 +61,24 @@ class ModelConfig:
     top_k: int = 0
     capacity_factor: float = 1.25
     first_dense_layers: int = 0
+    #: ``(lo, hi)``: this chip holds experts ``lo .. hi-1`` of the
+    #: ``n_experts`` the router scores (expert parallelism's share); None
+    #: holds them all
+    expert_range: Optional[tuple] = None
+    shared_ff: int = 0             # shared SwiGLU expert width (0: none)
     # --- ssm / hybrid ---
     ssm_state: int = 64
     ssm_head_dim: int = 64
     ssm_expand: int = 2
     shared_attn_every: int = 0     # zamba2: shared block period
+    #: per-layer mixer, "mamba" | "attention" (granite hybrid); () = n/a
+    layer_types: tuple = ()
+    # --- residual / logit scaling (granite) ---
+    norm_eps: float = 1e-6
+    attn_scale: Optional[float] = None   # scores scale; None: 1/sqrt(hd)
+    embed_scale: float = 1.0
+    residual_scale: float = 1.0
+    logit_scale: float = 1.0             # logits are divided by this
     # --- enc-dec / vlm ---
     n_enc_layers: int = 0
     n_frames: int = 1500           # whisper stub frontend length
@@ -196,6 +209,14 @@ class BaseModel:
         — the continuous-batching path of ``ServingEngine``."""
         return False
 
+    def slot_state_rows(self) -> dict:
+        """Recurrent state the slot cache keeps per slot beside its KV
+        pages, ``{name: shape of one layer's row}``; empty for families
+        whose only per-request state is KV.  A model with state rows
+        shares no prefix pages (a prefix's KV without its state would be
+        wrong)."""
+        return {}
+
     def slot_param_axes(self) -> dict:
         """Logical sharding axes mirroring ``slot_params``' structure
         leaf-for-leaf (per-layer entries carry the stacked block axes with
@@ -246,5 +267,6 @@ def register_family(name: str):
 
 
 def get_model(cfg: ModelConfig) -> BaseModel:
-    from . import mamba, moe, paper_nets, rwkv, transformer, vlm, whisper  # noqa
+    from . import (granite_hybrid, mamba, moe, paper_nets, rwkv,  # noqa
+                   transformer, vlm, whisper)
     return _REGISTRY[cfg.family](cfg)

@@ -50,6 +50,26 @@ def _ssm_step(q, k, xc, w, state):
                                       init_state=state, return_state=True)
 
 
+def ssd_in_proj(cfg: ModelConfig, p, x):
+    """Mamba2 in-projection, split: ``(z, xBC, dt)``."""
+    din, H, hd, N = _mamba_dims(cfg)
+    zxbcdt = tapir.linear(x, p["w_in"])
+    return (zxbcdt[..., :din], zxbcdt[..., din:2 * din + 2 * N],
+            zxbcdt[..., 2 * din + 2 * N:])
+
+
+def ssd_out_proj(cfg: ModelConfig, p, y, xc, z, dtype, eps: float = 1e-6):
+    """The ``D`` skip, the gated RMSNorm (``norm(y * silu(z))``) and the
+    out-projection.  y, xc: [B, S, H, hd]."""
+    B, S = y.shape[0], y.shape[1]
+    din = _mamba_dims(cfg)[0]
+    y = y + p["D"].astype(jnp.float32)[None, None, :, None] * \
+        xc.astype(jnp.float32)
+    y = y.reshape(B, S, din).astype(dtype)
+    y = L.rmsnorm(y * tapir.elemwise(z, "silu"), p["norm"], eps)
+    return tapir.linear(y, p["w_out"])
+
+
 def _mamba_dims(cfg: ModelConfig):
     din = cfg.ssm_expand * cfg.d_model
     hd = cfg.ssm_head_dim
@@ -117,10 +137,7 @@ class Zamba2(BaseModel):
         cfg = self.cfg
         B, S, d = x.shape
         din, H, hd, N = _mamba_dims(cfg)
-        zxbcdt = tapir.linear(x, p["w_in"])
-        z = zxbcdt[..., :din]
-        xBC = zxbcdt[..., din:2 * din + 2 * N]
-        dt = zxbcdt[..., 2 * din + 2 * N:]
+        z, xBC, dt = ssd_in_proj(cfg, p, x)
         xBC, new_conv = L.causal_conv1d(xBC, p["conv_w"], conv_state)
         xBC = tapir.elemwise(xBC, "silu")
         xc = xBC[..., :din].reshape(B, S, H, hd)
@@ -140,12 +157,7 @@ class Zamba2(BaseModel):
             y, new_ssm = ls_ops.linear_scan_chunked(
                 q, k, xc, w, chunk=64, init_state=ssm_state,
                 return_state=True)
-        y = y + p["D"].astype(jnp.float32)[None, None, :, None] * \
-            xc.astype(jnp.float32)
-        y = y.reshape(B, S, din).astype(x.dtype)
-        y = L.rmsnorm(y * tapir.elemwise(z, "silu"), p["norm"])
-        out = tapir.linear(y, p["w_out"])
-        return out, new_conv, new_ssm
+        return ssd_out_proj(cfg, p, y, xc, z, x.dtype), new_conv, new_ssm
 
     def _mamba_block_body(self, p, x):
         y, _, _ = self._ssd(p, L.rmsnorm(x, p["ln"]))

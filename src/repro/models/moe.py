@@ -24,16 +24,39 @@ def _moe_block_specs(cfg: ModelConfig, n_layers: int) -> dict:
     for key in ("wg", "wu", "wd"):
         spec.pop(key, None)
     d, ff, E = cfg.d_model, cfg.d_ff, cfg.n_experts
+    Eh = experts_held(cfg)
     pdt = cfg.param_dtype
     Lx = (n_layers,)
     spec["router"] = ParamSpec(Lx + (d, E), pdt, ("layers", "embed", None))
-    spec["ewg"] = ParamSpec(Lx + (E, d, ff), pdt,
+    spec["ewg"] = ParamSpec(Lx + (Eh, d, ff), pdt,
                             ("layers", "expert", "embed", "mlp"))
-    spec["ewu"] = ParamSpec(Lx + (E, d, ff), pdt,
+    spec["ewu"] = ParamSpec(Lx + (Eh, d, ff), pdt,
                             ("layers", "expert", "embed", "mlp"))
-    spec["ewd"] = ParamSpec(Lx + (E, ff, d), pdt,
+    spec["ewd"] = ParamSpec(Lx + (Eh, ff, d), pdt,
                             ("layers", "expert", "mlp", "embed"))
+    if cfg.shared_ff:
+        sf = cfg.shared_ff
+        spec["swg"] = ParamSpec(Lx + (d, sf), pdt, ("layers", "embed", "mlp"))
+        spec["swu"] = ParamSpec(Lx + (d, sf), pdt, ("layers", "embed", "mlp"))
+        spec["swd"] = ParamSpec(Lx + (sf, d), pdt, ("layers", "mlp", "embed"))
     return spec
+
+
+def experts_held(cfg: ModelConfig) -> int:
+    """Experts whose weights this chip holds (all of them without an
+    ``expert_range``)."""
+    if cfg.expert_range is None:
+        return cfg.n_experts
+    lo, hi = cfg.expert_range
+    return hi - lo
+
+
+def _held_routes(eidx, keep, *, lo: int, hi: int):
+    """Routes to the experts held here, ``lo .. hi-1``: expert ids made
+    local, every other route dropped from ``keep`` (its part of the
+    result is the absent experts' chips' to add)."""
+    mine = (eidx >= lo) & (eidx < hi)
+    return jnp.where(mine, eidx - lo, 0), keep & mine
 
 
 def _route_topk(xt, router, *, k: int, e: int, cap: int):
@@ -248,6 +271,12 @@ class MoELM(DenseLM):
         xt = x.reshape(T, d)
         gate, eidx, pos, keep = tapir.lift(_route_topk, xt, p["router"],
                                            k=K, e=E, cap=cap)
+        held = None
+        if cfg.expert_range is not None:
+            # the router scores all E experts; only the held ones run here
+            lo, hi = cfg.expert_range
+            eidx, keep = tapir.lift(_held_routes, eidx, keep, lo=lo, hi=hi)
+            held, E = (lo, hi, E), hi - lo
         src = tapir.lift(_dispatch_src, xt, keep, k=K, cdt=cdt)
         ef, pf = eidx.reshape(T * K), pos.reshape(T * K)
         xe = tapir.scatter_new((E, cap, d), cdt, (ef, pf), src, mode="add")
@@ -255,7 +284,8 @@ class MoELM(DenseLM):
         # expert dim of the dispatch/combine buffers shards over "model"
         # (captured as node annotations, replayed at lowering)
         xe = shard_act(xe, "expert", None, None)
-        ye = tapir.expert_mlp(xe, p["ewg"], p["ewu"], p["ewd"], cfg.act)
+        ye = tapir.expert_mlp(xe, p["ewg"], p["ewu"], p["ewd"], cfg.act,
+                              held=held)
         ye = shard_act(ye, "expert", None, None)
         fetched = tapir.gather(ye, (ef, pf))
         out = tapir.lift(_combine_expert_out, fetched, keep, gate,

@@ -128,6 +128,10 @@ class DenseLM(BaseModel):
         return tapir.linear(tapir.linear(x, p["wu"], activation=cfg.act),
                             p["wd"])
 
+    def _residual(self, x, y):
+        """A block's residual add (scaled in families that scale it)."""
+        return x + y
+
     def _norm(self, x, scale):
         return L.rmsnorm(x, scale) if self.cfg.norm == "rmsnorm" \
             else L.layernorm(x, scale)
@@ -323,8 +327,9 @@ class DenseLM(BaseModel):
         P = 1 + slots * pps + shared_pages
         shape = (P, pl, cfg.n_kv_heads, cfg.hd)
         ptab = np.stack([identity_row(s, pps) for s in range(slots)])
-        return {"k": [jnp.zeros(shape, kv) for _ in range(cfg.n_layers)],
-                "v": [jnp.zeros(shape, kv) for _ in range(cfg.n_layers)],
+        n = self._kv_layers()
+        return {"k": [jnp.zeros(shape, kv) for _ in range(n)],
+                "v": [jnp.zeros(shape, kv) for _ in range(n)],
                 "ptab": jnp.asarray(ptab),
                 "pos": jnp.zeros((slots,), jnp.int32)}
 
@@ -341,8 +346,12 @@ class DenseLM(BaseModel):
         indirection), so splitting them would turn every decode write
         into a collective."""
         a = (None, None, "kv", None)
-        L = self.cfg.n_layers
+        L = self._kv_layers()
         return {"k": [a] * L, "v": [a] * L, "ptab": (), "pos": ()}
+
+    def _kv_layers(self) -> int:
+        """Layers that keep KV pages."""
+        return self.cfg.n_layers
 
     def slot_params(self, params) -> dict:
         """Per-layer param dicts + head params with STABLE array ids:
@@ -373,6 +382,18 @@ class DenseLM(BaseModel):
     def _rope_frac(self) -> float:
         return 0.5 if self.cfg.rope == "half" else 1.0
 
+    def _slot_rope(self, q, k, rope_cos, rope_sin, pos, per_slot: bool):
+        """Positions on the slot path's q and k: RoPE rows gathered at
+        ``pos`` (one per slot in decode, one per prompt row in prefill)."""
+        shape = (q.shape[0], 1, rope_cos.shape[-1]) if per_slot else None
+        cos = tapir.gather(rope_cos, (pos,))
+        cos = cos.reshape(shape) if per_slot else cos
+        sin = tapir.gather(rope_sin, (pos,))
+        sin = sin.reshape(shape) if per_slot else sin
+        frac = self._rope_frac()
+        return (L.apply_rope(q, cos, sin, frac),
+                L.apply_rope(k, cos, sin, frac))
+
     def _slot_attn_body(self, p, x, rope_cos, rope_sin, ck, cv, pos, ptab):
         """Attention sub-block over the paged pool.  All data-dependent
         pieces are graph values: RoPE rows gather at ``pos``, the write
@@ -398,12 +419,7 @@ class DenseLM(BaseModel):
         q = shard_act(q, "batch", None, "heads", None)
         k = shard_act(k, "batch", None, "kv", None)
         v = shard_act(v, "batch", None, "kv", None)
-        rot2 = rope_cos.shape[-1]
-        cos = tapir.gather(rope_cos, (pos,)).reshape(B, 1, rot2)
-        sin = tapir.gather(rope_sin, (pos,)).reshape(B, 1, rot2)
-        frac = self._rope_frac()
-        q = L.apply_rope(q, cos, sin, frac)
-        k = L.apply_rope(k, cos, sin, frac)
+        q, k = self._slot_rope(q, k, rope_cos, rope_sin, pos, per_slot=True)
         pidx, off = _page_coords_t(pos, page_len=int(ck.shape[1]))
         phys = tapir.gather(ptab, (np.arange(B), pidx))
         ck = tapir.scatter(ck, (phys, off), k.reshape(B, Hkv, hd))
@@ -414,7 +430,7 @@ class DenseLM(BaseModel):
         o = shard_act(o, "batch", None, "heads", None)
         # all-gather before wo so GSPMD never k-splits it (see _attn)
         o = shard_act(o.reshape(B, 1, H * hd), "batch", None, None)
-        x = x + tapir.linear(o, p["wo"])
+        x = self._residual(x, tapir.linear(o, p["wo"]))
         return shard_act(x, "batch", None, None), ck, cv
 
     def _slot_block_body(self, p, x, rope_cos, rope_sin, ck, cv, pos, ptab):
@@ -444,11 +460,8 @@ class DenseLM(BaseModel):
         q = shard_act(q, None, None, "heads", None)
         k = shard_act(k, None, None, "kv", None)
         v = shard_act(v, None, None, "kv", None)
-        cos = tapir.gather(rope_cos, (pos_vec,))
-        sin = tapir.gather(rope_sin, (pos_vec,))
-        frac = self._rope_frac()
-        q = L.apply_rope(q, cos, sin, frac)
-        k = L.apply_rope(k, cos, sin, frac)
+        q, k = self._slot_rope(q, k, rope_cos, rope_sin, pos_vec,
+                               per_slot=False)
         ck = tapir.scatter(ck, (phys_vec, off_vec), k.reshape(S, Hkv, hd))
         cv = tapir.scatter(cv, (phys_vec, off_vec), v.reshape(S, Hkv, hd))
         ck = shard_act(ck, None, None, "kv", None)
@@ -457,7 +470,7 @@ class DenseLM(BaseModel):
         o = shard_act(o, None, None, "heads", None)
         # all-gather before wo so GSPMD never k-splits it (see _attn)
         o = shard_act(o.reshape(B, S, H * hd), None, None, None)
-        x = x + tapir.linear(o, p["wo"])
+        x = self._residual(x, tapir.linear(o, p["wo"]))
         return x, ck, cv
 
     def _slot_prefill_block_body(self, p, x, rope_cos, rope_sin, ck, cv,
@@ -467,6 +480,23 @@ class DenseLM(BaseModel):
             prow, vlen)
         x = x + self._mlp(p, self._norm(x, p["ln2"]), gather_hidden=True)
         return x, ck, cv
+
+    def _prefill_targets(self, cache, slot: int, Sb: int, start: int):
+        """Where rows ``[start, start + Sb)`` of a prompt in slot ``slot``
+        land: (absolute positions, and as device arrays — rebindable region
+        inputs, not baked-in consts — each row's physical page and in-page
+        offset, the slot's page row, the valid length).  Rows past
+        ``max_len`` go to the trash page."""
+        pl = int(cache["k"][0].shape[1])
+        row = np.asarray(cache["ptab"][slot])
+        pps = row.shape[0]
+        p_abs = start + np.arange(Sb)
+        ok = p_abs < pps * pl
+        pidx = np.minimum(p_abs // pl, pps - 1)
+        phys = np.where(ok, row[pidx], 0).astype(np.int32)
+        off = np.where(ok, p_abs % pl, 0).astype(np.int32)
+        return (p_abs, jnp.asarray(phys), jnp.asarray(off), jnp.asarray(row),
+                jnp.asarray(start + Sb, jnp.int32))
 
     def _slot_head_body(self, hp, x):
         x = self._norm(x, hp["ln_f"])
@@ -524,26 +554,14 @@ class DenseLM(BaseModel):
         cfg = self.cfg
         Sb = tokens.shape[1]
         with span("model.prefill", tokens=Sb):
-            pl = int(cache["k"][0].shape[1])
-            row = np.asarray(cache["ptab"][slot])
-            pps = row.shape[0]
-            max_len = pps * pl
+            p_abs, phys_vec, off_vec, prow, vlen = self._prefill_targets(
+                cache, slot, Sb, start)
+            max_len = cache["ptab"].shape[1] * int(cache["k"][0].shape[1])
             h = self._embed({"embed": sp["embed"]}, tokens)
             cos_t, sin_t = L.full_rope_table(max(max_len, Sb), cfg.hd,
                                              fraction=self._rope_frac())
-            p_abs = start + np.arange(Sb)
-            ok = p_abs < max_len
-            pidx = np.minimum(p_abs // pl, pps - 1)
-            phys = np.where(ok, row[pidx], 0).astype(np.int32)
-            off = np.where(ok, p_abs % pl, 0).astype(np.int32)
-            pos_clip = np.minimum(p_abs,
-                                  cos_t.shape[0] - 1).astype(np.int32)
-            # device arrays: rebindable region inputs, not baked-in consts
-            pos_vec = jnp.asarray(pos_clip)
-            phys_vec = jnp.asarray(phys)
-            off_vec = jnp.asarray(off)
-            prow = jnp.asarray(row)
-            vlen = jnp.asarray(start + Sb, jnp.int32)
+            pos_vec = jnp.asarray(np.minimum(
+                p_abs, cos_t.shape[0] - 1).astype(np.int32))
             bodies = self._slot_prefill_bodies()
             blks = {kind: tapir.parallel_region(
                         fn, name=f"slot_{kind}_prefill")

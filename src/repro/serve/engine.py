@@ -34,9 +34,18 @@ ambient mesh (the mesh fingerprint is part of every program key), the
 (slots over the data axes, heads over ``model`` when divisible) so the
 donated scatter writes stay in place per shard.  Per-request outputs are
 bitwise-identical to the single-device slot engine.  Only families
-without slot support (SSM/hybrid/encdec) still use the pjit'd padded-wave
-loop (``make_prefill_step`` / ``make_decode_step``, KV sequence dim
-sharded as "kvseq").
+without slot support (RWKV, Zamba2, enc-dec) still use the pjit'd
+padded-wave loop (``make_prefill_step`` / ``make_decode_step``, KV
+sequence dim sharded as "kvseq").
+
+**State rows.**  A family that keeps recurrent state per request
+(``model.slot_state_rows()``, granite's Mamba2 layers) holds one row of
+each state array per slot beside its KV pages.  Admission resets the
+slot's rows and the prefill writes the prompt's final state into them;
+each decode step advances them in place; parking copies them to a park
+row and resuming copies them back.  Such a model shares no prefix pages
+(a prefix's KV without its state would be wrong), so its shared region
+only parks preempted requests.
 
 ``ServeConfig.regions=False`` is the per-op control: the same slot loop
 with every op dispatched eagerly.  Every ``run``/``run_wave`` call
@@ -47,8 +56,10 @@ inter-token gaps from each request's ``token_times``).
 **Spans.**  Under a profiler session the slot loop writes host spans
 (``repro.spans``): ``serve.tick`` per scheduler iteration, holding
 ``serve.admit`` (with its ``serve.pages.*`` operations and the blocking
-``serve.prefill``), the blocking ``serve.decode`` (``kv_pages``: the
-live KV pages the step reads, summed over slots), ``serve.release``,
+``serve.prefill``; ``state_reset``: the bytes of state rows it reset),
+the blocking ``serve.decode`` (``kv_pages``: the live KV pages the step
+reads, summed over slots; ``state_rows``: the slots whose state rows it
+advances), ``serve.release``,
 ``serve.preempt`` and ``serve.ckpt``.  The model's ``model.prefill`` /
 ``model.decode`` spans sit inside the two blocking ones and time the
 host's dispatch alone.
@@ -72,8 +83,10 @@ from repro.core.tapir import (TapirConfig, cache_stats, invalidate_mesh,
 from repro.dist.fault import Fault, FaultInjector, StragglerWatchdog
 from repro.dist.sharding import (batch_pspec, logical_to_pspec,
                                  param_shardings)
-from repro.serve.pages import (PagePool, copy_cache_pages, identity_row,
-                               preempt_cost, private_page)
+from repro.serve.pages import (PagePool, copy_cache_pages,
+                               default_shared_pages, identity_row,
+                               page_geometry, preempt_cost, private_page,
+                               reset_state_rows, state_row_bytes)
 from repro.spans import span
 
 
@@ -371,10 +384,19 @@ class ServingEngine:
         # slot scheduling runs wherever the family implements the slot
         # API — including TP meshes, where the slot regions capture under
         # the ambient mesh and replay their sharding constraints at
-        # lowering.  Only families without slot support (SSM/hybrid/
-        # encdec) use the pjit'd padded-wave loop.
+        # lowering.  Only families without slot support (RWKV, Zamba2,
+        # enc-dec) use the pjit'd padded-wave loop.
         self._slot_capable = getattr(model, "supports_slots",
                                      lambda: False)()
+        # per-slot recurrent state beside the KV pages: a prefix's pages
+        # without its state would be wrong, so such a model shares no
+        # prefix, and its shared region only parks preempted requests
+        self._state = bool(self._slot_capable and model.slot_state_rows())
+        self._prefix_sharing = cfg.prefix_sharing and not self._state
+        self._shared_pages = cfg.shared_pages
+        if self._shared_pages is None and self._state:
+            self._shared_pages = default_shared_pages(
+                batch, page_geometry(max_len, cfg.page_len)[1], True)
         # the pjit'd padded-wave steps are only reachable for slot-less
         # families, so they build lazily on first use — a dense/MoE engine
         # (mesh or not) never pays for them
@@ -441,11 +463,11 @@ class ServingEngine:
         first constrained write and break the donation)."""
         cfg = self.cfg
         cache = self.model.init_slot_cache(self.slots, self.max_len,
-                                           cfg.page_len, cfg.shared_pages)
+                                           cfg.page_len, self._shared_pages)
         if self.mesh is not None and getattr(self.mesh, "size", 1) > 1:
             sh = slot_cache_shardings(self.model, self.mesh, self.slots,
                                       self.max_len, cfg.page_len,
-                                      cfg.shared_pages)
+                                      self._shared_pages)
             cache = jax.tree_util.tree_map(jax.device_put, cache, sh)
         return cache
 
@@ -474,7 +496,7 @@ class ServingEngine:
         """ShapeDtypeStruct pytree of the checkpointable device state."""
         return {"cache": self.model.slot_cache_specs(
                     self.slots, self.max_len, self.cfg.page_len,
-                    self.cfg.shared_pages),
+                    self._shared_pages),
                 "rng": jax.ShapeDtypeStruct((2,), jnp.uint32)}
 
     def _slot_state_shardings(self):
@@ -483,14 +505,14 @@ class ServingEngine:
         return {"cache": slot_cache_shardings(self.model, self.mesh,
                                               self.slots, self.max_len,
                                               self.cfg.page_len,
-                                              self.cfg.shared_pages),
+                                              self._shared_pages),
                 "rng": NamedSharding(self.mesh, P())}
 
     def _fresh_slot_state(self, requests) -> _SlotRunState:
         for r in requests:
             r.out, r.done, r.token_times = [], False, []
         pool = PagePool(self.slots, self.max_len, self.cfg.page_len,
-                        self.cfg.shared_pages)
+                        self._shared_pages, self._state)
         return _SlotRunState(
             cache=self._init_slot_cache(),
             # greedy today; checkpointed so a sampler slots into the same
@@ -573,7 +595,7 @@ class ServingEngine:
                 tokens=np.asarray(meta["tokens"], np.int32).reshape(-1, 1),
                 pool=PagePool.from_meta(meta["pool"], self.slots,
                                         self.max_len, self.cfg.page_len,
-                                        self.cfg.shared_pages),
+                                        self._shared_pages, self._state),
                 ptab_host=np.array(state["cache"]["ptab"]),
                 pos_host=np.array(state["cache"]["pos"], np.int64),
                 pending=list(meta["pending"]),
@@ -736,7 +758,8 @@ class ServingEngine:
             if not replaying:
                 ft["_qwait"].append(time.perf_counter() - ft["_t0"])
             prompt = np.asarray(r.prompt, np.int32)
-            k, pages = pool.lookup(prompt) if cfg.prefix_sharing else (0, [])
+            k, pages = pool.lookup(prompt) if self._prefix_sharing \
+                else (0, [])
             row = identity_row(s, pool.pps)
             start = 0
             if k > 0:
@@ -761,7 +784,11 @@ class ServingEngine:
             padded = np.zeros((1, min(bucket_pow2(len(suf)), self.max_len)),
                               np.int32)
             padded[0, :len(suf)] = suf
-            adm.set_metadata(bucket=padded.shape[1], prefix_pages=k)
+            # a fresh request starts from zero state: its rows are reset
+            # before its prefill writes the prompt's final state into them
+            reset_state_rows(rs.cache, s)
+            adm.set_metadata(bucket=padded.shape[1], prefix_pages=k,
+                             state_reset=state_row_bytes(rs.cache))
             with span("serve.prefill", rid=r.rid, tokens=len(suf)):
                 logits, rs.cache = model.prefill_into_slot(
                     sp, jnp.asarray(padded), rs.cache, s, plen, start=start)
@@ -774,7 +801,7 @@ class ServingEngine:
                 rs.st["admitted"] += 1
                 rs.st["tokens"] += 1
                 ft["_ttft"].append(now - ft["_t0"])
-            if cfg.prefix_sharing and k == 0:
+            if self._prefix_sharing and k == 0:
                 # total miss: publish the prompt-covering pages so the NEXT
                 # request sharing this prefix prefills only its suffix
                 pool.publish(rs.cache, s, prompt)
@@ -850,7 +877,8 @@ class ServingEngine:
                     n_out=len(victim.out), page_bytes=self._page_bytes(rs),
                     pps=pool.pps, page_len=pool.page_len,
                     model_flops_per_tok=self._flops_tok,
-                    step_s=(wd.p50 or 1e-3)).arm
+                    step_s=(wd.p50 or 1e-3),
+                    row_bytes=state_row_bytes(rs.cache)).arm
             if arm == "park":
                 if pool.park(rs.cache, victim.rid, s, length):
                     rs.parked[victim.rid] = {"tok": int(rs.tokens[s, 0]),
@@ -943,7 +971,11 @@ class ServingEngine:
                 # ones, from the host's mirror of the lengths
                 live = np.clip(rs.pos_host + 1, 1, self.max_len)
                 kv_pages = int(np.sum(-(-live // rs.pool.page_len)))
-                with span("serve.decode", step=rs.step, kv_pages=kv_pages):
+                # state rows the step advances: every slot holding a request
+                state_rows = int(np.count_nonzero(rs.pos_host)) \
+                    if self._state else 0
+                with span("serve.decode", step=rs.step, kv_pages=kv_pages,
+                          state_rows=state_rows):
                     logits, rs.cache = model.decode_step_slots(
                         sp, jnp.asarray(rs.tokens), rs.cache)
                     nxt = np.asarray(

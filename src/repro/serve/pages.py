@@ -12,6 +12,9 @@ fixed-size pages plus a per-slot page table:
   *private* run (slot ``s``, logical page ``j`` owns physical page
   ``1 + s*pps + j`` — no allocator needed), and the tail is the
   *shared region* this module manages.
+* **state rows** — a model with per-slot recurrent state keeps, per
+  layer, arrays of ``slots + park rows`` rows beside the pools (slot
+  ``s`` owns row ``s``); a parked request's rows wait in a park row.
 * **ptab** — ``[slots, pps]`` int32 device array mapping each slot's
   logical page to a physical page.  Decode/prefill read the KV view by
   gathering ``pool[ptab[s]]``; page indirection is DATA, not shape, so
@@ -69,6 +72,16 @@ def page_geometry(max_len: int, page_len: Optional[int] = None):
     return page_len, max_len // page_len
 
 
+def default_shared_pages(slots: int, pps: int, state_rows: bool) -> int:
+    """Shared-region pages when the configuration names none: one slot's
+    worth per slot, for shared prefixes.  A model with per-slot state rows
+    shares no prefix, so its region only parks preempted requests: one
+    slot's worth (pages and a state row) per eight slots."""
+    if state_rows:
+        return max(1, slots // 8) * pps
+    return slots * pps
+
+
 def private_page(slot: int, j: int, pps: int) -> int:
     """Physical id of slot ``slot``'s logical page ``j``."""
     return 1 + slot * pps + j
@@ -100,6 +113,34 @@ def copy_pages(pool, src_ids, dst_ids):
     dst = jnp.asarray(np.asarray(dst_ids, np.int32))
     rows = _gather_rows(pool, src)   # read BEFORE the donating write
     return _set_rows(pool, dst, rows)
+
+
+_zero_row = jax.jit(lambda rows, i: rows.at[i].set(
+    jnp.zeros(rows.shape[1:], rows.dtype)), donate_argnums=0)
+
+
+def state_row_bytes(cache) -> int:
+    """Bytes of one slot's state rows, every layer (0 without state)."""
+    return sum(int(np.prod(a.shape[1:])) * a.dtype.itemsize
+               for a in jax.tree_util.tree_leaves(cache.get("state", {})))
+
+
+def reset_state_rows(cache, slot: int) -> None:
+    """Zero slot ``slot``'s row of every state array, in place."""
+    st = cache.get("state")
+    if not st:
+        return
+    i = jnp.asarray(slot, jnp.int32)
+    cache["state"] = jax.tree_util.tree_map(lambda a: _zero_row(a, i), st)
+
+
+def copy_state_rows(cache, src: int, dst: int) -> None:
+    """Row ``dst`` <- row ``src`` of every state array, in place."""
+    st = cache.get("state")
+    if not st:
+        return
+    cache["state"] = jax.tree_util.tree_map(
+        lambda a: copy_pages(a, [src], [dst]), st)
 
 
 def copy_cache_pages(cache, src_ids, dst_ids) -> None:
@@ -146,15 +187,20 @@ class PagePool:
 
     def __init__(self, slots: int, max_len: int,
                  page_len: Optional[int] = None,
-                 shared_pages: Optional[int] = None):
+                 shared_pages: Optional[int] = None,
+                 state_rows: bool = False):
         self.page_len, self.pps = page_geometry(max_len, page_len)
         self.slots, self.max_len = slots, max_len
         if shared_pages is None:
-            shared_pages = slots * self.pps
+            shared_pages = default_shared_pages(slots, self.pps, state_rows)
         self.shared_start = 1 + slots * self.pps
         self.n_shared = shared_pages
         self.free = list(range(self.shared_start,
                                self.shared_start + shared_pages))
+        # a model with state rows parks each request's rows in one of the
+        # rows past the slots' own (``state_rows`` of its slot cache)
+        self.free_rows = list(range(slots, slots + shared_pages // self.pps)
+                              ) if state_rows else []
         self.entries: dict[str, _Entry] = {}
         self.clock = 0                      # LRU tick
         # per-slot binding: entry hash (or None) + #shared pages bound
@@ -254,36 +300,50 @@ class PagePool:
     def park(self, cache, rid: int, slot: int, length: int) -> bool:
         """Copy the victim's written PRIVATE pages into shared-region
         pages (its shared prefix stays bound — refcount held while
-        parked).  False = no room; caller falls back to replay."""
+        parked), and its state rows, where the model keeps any, into a
+        park row.  False = no room; caller falls back to replay."""
         k = self.slot_bound[slot]
         n_used = -(-length // self.page_len)         # ceil
         priv = list(range(k, n_used))
-        with span("serve.pages.park", pages=len(priv)):
+        has_state = bool(cache.get("state"))
+        with span("serve.pages.park", pages=len(priv),
+                  state_bytes=state_row_bytes(cache)):
+            if has_state and not self.free_rows:
+                return False
             pages = self._alloc(len(priv)) if priv else []
             if pages is None:
                 return False
             if priv:
                 src = [private_page(slot, j, self.pps) for j in priv]
                 copy_cache_pages(cache, src, pages)
+            row = None
+            if has_state:
+                row = self.free_rows.pop(0)
+                copy_state_rows(cache, slot, row)
         self.parked[rid] = {"pages": pages, "first": k, "length": length,
                             "entry": self.slot_entry[slot],
-                            "bound": k}
+                            "bound": k, "row": row}
         # keep the entry refcount: the parked request still binds it
         self.slot_entry[slot] = None
         self.slot_bound[slot] = 0
         return True
 
     def resume(self, cache, rid: int, slot: int) -> dict:
-        """Copy a parked request's pages back into ``slot``'s private run
-        and free them; rebind its shared prefix.  Returns the park record
-        (caller rebuilds the ptab row and pos)."""
+        """Copy a parked request's pages (and state rows) back into
+        ``slot``'s private run and free them; rebind its shared prefix.
+        Returns the park record (caller rebuilds the ptab row and pos)."""
         rec = self.parked.pop(rid)
-        with span("serve.pages.resume", pages=len(rec["pages"])):
+        with span("serve.pages.resume", pages=len(rec["pages"]),
+                  state_bytes=state_row_bytes(cache)
+                  if rec["row"] is not None else 0):
             if rec["pages"]:
                 dst = [private_page(slot, rec["first"] + i, self.pps)
                        for i in range(len(rec["pages"]))]
                 copy_cache_pages(cache, rec["pages"], dst)
                 self.free.extend(rec["pages"])
+            if rec["row"] is not None:
+                copy_state_rows(cache, rec["row"], slot)
+                self.free_rows.append(rec["row"])
         self.slot_entry[slot] = rec["entry"]
         self.slot_bound[slot] = rec["bound"]
         return rec
@@ -293,6 +353,8 @@ class PagePool:
         if rec is None:
             return
         self.free.extend(rec["pages"])
+        if rec["row"] is not None:
+            self.free_rows.append(rec["row"])
         if rec["entry"] is not None and rec["entry"] in self.entries:
             self.entries[rec["entry"]].refs -= 1
 
@@ -306,6 +368,7 @@ class PagePool:
     def to_meta(self) -> dict:
         return {
             "free": [int(p) for p in self.free],
+            "free_rows": [int(r) for r in self.free_rows],
             "clock": int(self.clock),
             "slot_entry": list(self.slot_entry),
             "slot_bound": [int(b) for b in self.slot_bound],
@@ -318,16 +381,19 @@ class PagePool:
                                 "first": int(v["first"]),
                                 "length": int(v["length"]),
                                 "entry": v["entry"],
-                                "bound": int(v["bound"])}
+                                "bound": int(v["bound"]),
+                                "row": v["row"]}
                        for r, v in self.parked.items()},
         }
 
     @classmethod
     def from_meta(cls, meta: dict, slots: int, max_len: int,
                   page_len: Optional[int] = None,
-                  shared_pages: Optional[int] = None) -> "PagePool":
-        pool = cls(slots, max_len, page_len, shared_pages)
+                  shared_pages: Optional[int] = None,
+                  state_rows: bool = False) -> "PagePool":
+        pool = cls(slots, max_len, page_len, shared_pages, state_rows)
         pool.free = list(meta["free"])
+        pool.free_rows = list(meta["free_rows"])
         pool.clock = int(meta["clock"])
         pool.slot_entry = list(meta["slot_entry"])
         pool.slot_bound = list(meta["slot_bound"])
@@ -340,7 +406,8 @@ class PagePool:
                                 "first": int(v["first"]),
                                 "length": int(v["length"]),
                                 "entry": v["entry"],
-                                "bound": int(v["bound"])}
+                                "bound": int(v["bound"]),
+                                "row": v["row"]}
                        for r, v in meta["parked"].items()}
         return pool
 
@@ -360,11 +427,13 @@ class PreemptCost:
 
 def preempt_cost(cost_model, *, length: int, prefix_len: int,
                  n_out: int, page_bytes: int, pps: int, page_len: int,
-                 model_flops_per_tok: float, step_s: float) -> PreemptCost:
+                 model_flops_per_tok: float, step_s: float,
+                 row_bytes: int = 0) -> PreemptCost:
     """Roofline comparison of the two eviction arms for one victim.
 
-    * **park**: copy the written private pages out now and back on
-      resume — ``2 * bytes / hbm_bw`` (plus a spawn per copy call).
+    * **park**: copy the written private pages (and the state rows) out
+      now and back on resume — ``2 * bytes / hbm_bw`` (plus a spawn per
+      copy call).
     * **replay**: drop the pages; on re-admission re-prefill the
       non-shared part of the prompt (``length - n_out - prefix_len``
       tokens of FLOPs) and replay the ``n_out - 1`` recorded tokens
@@ -372,7 +441,7 @@ def preempt_cost(cost_model, *, length: int, prefix_len: int,
     """
     n_pages = -(-length // page_len) - prefix_len // page_len
     n_pages = max(0, min(n_pages, pps))
-    park_bytes = 2.0 * n_pages * page_bytes
+    park_bytes = 2.0 * (n_pages * page_bytes + row_bytes)
     park_s = park_bytes / cost_model.hbm_bw + 2 * cost_model.spawn_s
     re_prefill_tok = max(0, length - (n_out - 1) - prefix_len)
     replay_s = (re_prefill_tok * model_flops_per_tok

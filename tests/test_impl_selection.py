@@ -239,7 +239,7 @@ def test_every_library_op_gets_an_impl_and_cost_table():
     assert mm.schedule.impl == "einsum"   # no pallas GEMM off-TPU
     assert isinstance(mm.schedule.impl_costs["einsum"], float)
     assert set(IMPL_REGISTRY) == {"matmul", "attention", "paged_attention",
-                                  "linear_scan", "conv2d"}
+                                  "linear_scan", "ssm_state_step", "conv2d"}
 
 
 # ---------------------------------------------------------------------------

@@ -148,3 +148,62 @@ def test_decode_span_counts_live_kv_pages(tmp_path):
             for p in rec.pos]
     assert got == want and len(got) == eng.last_stats["decode_steps"]
     assert min(got) >= 2 and max(got) < 2 * max_len // page_len
+
+
+def _span_args(path, name):
+    from jax.profiler import ProfileData
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            out.extend((e.start_ns, dict(e.stats)) for e in line.events
+                       if e.name == name)
+    return [args for _, args in sorted(out, key=lambda t: t[0])]
+
+
+def test_state_rows_in_decode_admit_park_and_resume_spans(tmp_path):
+    """A model with per-slot state rows (granite's hybrid at smoke size):
+    ``serve.decode``'s ``state_rows`` is the slots holding a request, from
+    the host's lengths; each ``serve.admit`` resets one slot's rows
+    (``state_reset``, bytes), and ``serve.pages.park`` / ``.resume`` move
+    them (``state_bytes``)."""
+    import dataclasses
+
+    import repro.configs as C
+    from repro.models.base import get_model
+    from repro.serve import Request, ServeConfig, ServingEngine
+    from repro.serve.pages import state_row_bytes
+    cfg = dataclasses.replace(C.get_smoke("granite_4_0_h_small"),
+                              compute_dtype="float32")
+    model = get_model(cfg)
+    params = model.init_params(jax.random.PRNGKey(0))
+    rec = _PosRecorder(model)
+    eng = ServingEngine(rec, params, batch=2, max_len=64,
+                        cfg=ServeConfig(target="cpu", page_len=8,
+                                        preempt_mode="park"))
+    rng = np.random.default_rng(0)
+    reqs = [Request(rid=i, prompt=rng.integers(1, 100, size=n)
+                    .astype(np.int32), max_new=m, priority=p,
+                    arrival_step=a)
+            for i, (n, m, p, a) in enumerate([(7, 14, 0, 0), (17, 5, 0, 0),
+                                              (3, 4, 5, 2)])]
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.run(reqs)
+    finally:
+        jax.profiler.stop_trace()
+    assert eng.last_stats["parked"] == 1
+    path, = glob.glob(os.path.join(tmp_path, "**", "*.xplane.pb"),
+                      recursive=True)
+    rows = state_row_bytes(model.init_slot_cache(2, 64, 8))
+    din = cfg.ssm_expand * cfg.d_model
+    assert rows == 3 * (cfg.ssm_expand * cfg.d_model * cfg.ssm_state * 4
+                        + 3 * (din + 2 * cfg.ssm_state) * 4)
+    got = [a["state_rows"] for a in _span_args(path, "repro.serve.decode")]
+    assert got == [int(np.count_nonzero(p)) for p in rec.pos]
+    assert max(got) == 2
+    admits = _span_args(path, "repro.serve.admit")
+    assert len(admits) == 4                  # three requests, one resumed
+    assert sorted(a.get("state_reset", 0) for a in admits) == [0] + [rows] * 3
+    for name in ("repro.serve.pages.park", "repro.serve.pages.resume"):
+        args, = _span_args(path, name)
+        assert args["state_bytes"] == rows

@@ -5,7 +5,8 @@ host and XLA's TPU compiler compiles for its first device.  What the TPU
 compiler refuses (block shapes off the (8, 128) tiling, VMEM overruns,
 Mosaic lowering gaps) fails here instead of on the chip.  Shapes are the
 published widths of qwen2_5_3b (d_model 2048, d_ff 11008, 16/2 heads of
-128) and of rwkv6_7b's scan (64 heads of 64).
+128), of rwkv6_7b's scan (64 heads of 64) and of granite-4.0-h-small's
+Mamba2 state (128 heads of 64 x 128).
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU library, and test workers
@@ -27,6 +28,7 @@ from repro.kernels.flash_attention import ops as fa_ops
 from repro.kernels.fused_matmul import ops as fm_ops
 from repro.kernels.linear_scan import ops as ls_ops
 from repro.kernels.paged_attention import ops as pa_ops
+from repro.kernels.ssm_state_step import ops as ss_ops
 
 V5E = cost_model_for("TPU v5 lite")
 BF16 = jnp.bfloat16
@@ -270,3 +272,62 @@ def test_slot_decode_on_v5e_mesh_has_no_partial_sums(v5e_devices):
                      text)
     assert "all-gather" in ops, "nothing was split over the mesh"
     assert "all-reduce" not in ops and "reduce-scatter" not in ops
+
+
+def test_ssm_state_step_compiles_in_place_at_published_widths(one_chip):
+    """granite-4.0-h-small's decode state update: 96 slots (and 12 park
+    rows) of 128 heads x 64 x 128 f32 state, updated in place: the state
+    buffer is aliased, not copied."""
+    S, R, H, P, N = 96, 108, 128, 64, 128
+    g = ss_ops.heads_per_row(H, P)
+    f32 = jnp.float32
+    compiled = jax.jit(ss_ops.ssm_state_step, donate_argnums=0).lower(
+        _sds((R, H // g, N, g * P), f32, one_chip),
+        _sds((S, H), f32, one_chip), _sds((S, H, P), f32, one_chip),
+        _sds((S, N), f32, one_chip), _sds((S, N), f32, one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    state = R * H * P * N * 4
+    assert compiled.memory_analysis().alias_size_in_bytes >= state
+    assert compiled.memory_analysis().temp_size_in_bytes < state // 4
+
+
+def test_mamba_slot_decode_binds_the_state_kernel_on_v5e(one_chip):
+    """One decode step of a Mamba2 + MoE layer of granite-4.0-h-small
+    (d_model 4096, 128 heads of 64, state 128; 2 of 72 experts held to
+    keep the compile short) for 96 slots: the registry binds the state
+    kernel, and explain names the experts held."""
+    import dataclasses
+
+    import repro.configs as C
+    from repro.models.base import get_model
+
+    cfg = dataclasses.replace(
+        C.get_config("granite_4_0_h_small"), n_layers=1,
+        layer_types=("mamba",), expert_range=(0, 2), vocab=512,
+        param_dtype="bfloat16", compute_dtype="bfloat16")
+    model = get_model(cfg)
+    slots, max_len = 96, 4096
+
+    def layers_and_head():
+        sp = model.slot_params(model.init_params(jax.random.PRNGKey(0)))
+        return {**{k: v for k, v in sp.items() if k != "layers"},
+                "layers": [d for _, d in sp["layers"]]}
+
+    def step(lh, tokens, cache):
+        sp = {**lh, "layers": [("mamba", d) for d in lh["layers"]]}
+        return model.decode_step_slots(sp, tokens, cache)
+
+    cache = model.slot_cache_specs(slots, max_len)
+    sds = lambda tree: jax.tree_util.tree_map(   # noqa: E731
+        lambda v: _sds(v.shape, v.dtype, one_chip), tree)
+    tapir.clear_cache()
+    with use(TapirConfig(mode="tapir", backend="tpu", cost_model=V5E)):
+        text = jax.jit(step, donate_argnums=(2,)).lower(
+            sds(jax.eval_shape(layers_and_head)),
+            _sds((slots, 1), jnp.int32, one_chip), sds(cache)
+        ).compile().as_text()
+        report = tapir.explain()
+    tapir.clear_cache()
+    assert "ssm_state_step" in report and "impl=kernel" in report
+    assert "experts held: 0..1 of 72 routed" in report
+    assert "ssm_state_step" in text and "tpu_custom_call" in text

@@ -87,7 +87,10 @@ FORMAT_VERSION = 2
 #: 13: the one-position SSM state update is a library op
 #: (``ssm_state_step``, two output nodes lowered once) whose impl the
 #: registry binds: the in-place Pallas kernel or the jnp composite.
-PIPELINE_VERSION = "repro-pipeline-13"
+#: 14: a matmul bound to the fused kernel takes its row tile from the rows
+#: the kernel sees (every leading dim of the activation), so a decode GEMM
+#: runs as one row block: the same signature lowers to another program.
+PIPELINE_VERSION = "repro-pipeline-14"
 
 
 def _versions() -> dict:

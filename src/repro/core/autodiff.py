@@ -42,9 +42,9 @@ import jax.numpy as jnp
 from .ir import LIBRARY_OPS, Node, TaskGraph, TensorType, _freeze
 from .lowering import node_callable
 from .passes import mesh_fingerprint, optimize_graph
-from .schedule import (CostModel, pick_attention_tiles, pick_gqa_impl,
-                       pick_impl, pick_matmul_tiles, pick_remat,
-                       pick_scan_chunk)
+from .schedule import (CostModel, matmul_tiles, note_weight_passes,
+                       pick_attention_tiles, pick_gqa_impl, pick_impl,
+                       pick_remat, pick_scan_chunk)
 
 __all__ = ["grad"]
 
@@ -116,10 +116,8 @@ def _resolve_library_schedule(g: TaskGraph, node: Node, cm: CostModel,
     """Bind tile + impl on a library node at derivation time, with the
     exact same argmin ``assign_schedules`` re-binds on the joint graph —
     the VJP must replay the forward through the impl that actually runs."""
-    shape = node.ttype.shape
     if node.op == "matmul":
-        node.schedule.tile = pick_matmul_tiles(
-            shape[-2], shape[-1], node.attrs["k"], node.ttype.dtype, cm)
+        node.schedule.tile = matmul_tiles(g, node, cm)
     elif node.op == "attention":
         _, s, _, d_ = node.attrs["q_shape"]
         node.schedule.tile = pick_attention_tiles(
@@ -134,6 +132,8 @@ def _resolve_library_schedule(g: TaskGraph, node: Node, cm: CostModel,
     if node.attrs.get("exposed", False):
         pick_impl(g, node, cm, backend, mesh_axes=mesh_axes,
                   forced=forced.get(node.op))
+        if node.op == "matmul":
+            note_weight_passes(g, node)
     elif node.op in LIBRARY_OPS and not node.schedule.impl:
         node.schedule.impl = "opaque"
 

@@ -35,7 +35,7 @@ import numpy as np
 from .ir import LIBRARY_OPS, Node, TaskGraph, dtype_bytes
 from repro.kernels.flash_attention.ops import (attention_cost,
                                                kernel_unsupported)
-from repro.kernels.fused_matmul.ops import matmul_cost
+from repro.kernels.fused_matmul.ops import matmul_cost, row_block
 from repro.kernels.linear_scan.ops import SAFE_CHUNK, scan_cost
 from repro.kernels.paged_attention import ops as paged_ops
 from repro.kernels.ssm_state_step import ops as ssm_ops
@@ -158,6 +158,42 @@ def pick_matmul_tiles(m: int, n: int, k: int, dtype: str, cm: CostModel) -> dict
             break
     return {"bm": min(bm, max(m, 1)), "bn": min(bn, max(n, 1)),
             "bk": min(bk, max(k, 1))}
+
+
+def _weight_ndim(g: TaskGraph, node: Node) -> int:
+    """Rank of a matmul node's weight (2 when ``g`` does not hold it)."""
+    w = node.inputs[1] if len(node.inputs) > 1 else None
+    return len(g.nodes[w].ttype.shape) if w in g.nodes else 2
+
+
+def matmul_rows(g: TaskGraph, node: Node) -> int:
+    """Rows of the GEMM a matmul node runs.  With a 2-D weight the fused
+    kernel flattens every leading dim of the activation into rows, so a
+    ``[slots, 1, d]`` decode activation is ``slots`` rows, not 1; a stacked
+    or batched weight keeps ``shape[-2]`` rows per batch."""
+    shape = node.ttype.shape
+    if _weight_ndim(g, node) == 2:
+        return int(np.prod(shape[:-1]))
+    return shape[-2]
+
+
+def matmul_tiles(g: TaskGraph, node: Node, cm: CostModel) -> dict[str, int]:
+    """A matmul node's tiles, picked for the rows the kernel sees: one row
+    block up to ``pick_matmul_tiles``' cap, so a decode GEMM streams its
+    weight once a call."""
+    return pick_matmul_tiles(matmul_rows(g, node), node.ttype.shape[-1],
+                             node.attrs["k"], node.ttype.dtype, cm)
+
+
+def note_weight_passes(g: TaskGraph, node: Node) -> None:
+    """Note on a fused-kernel GEMM how often one call streams its weight
+    from HBM: once per row block of the kernel's grid."""
+    if node.schedule.impl != "fused_kernel":
+        return
+    rows = matmul_rows(g, node)
+    bm = row_block(node.schedule.tile["bm"], rows)
+    node.schedule.notes.append(
+        f"weight passes: {-(-rows // bm)} (rows {rows}, bm {bm})")
 
 
 def pick_attention_tiles(s_q: int, s_kv: int, d: int, dtype: str, cm: CostModel) -> dict[str, int]:
@@ -422,8 +458,7 @@ def matmul_candidates(g: TaskGraph, node: Node, cm: CostModel,
     eb = dtype_bytes(node.ttype.dtype)
     shard = shard_factor(node, mesh_axes)
     n_epi = len(node.epilogue)
-    w_nd = (len(g.nodes[node.inputs[1]].ttype.shape)
-            if len(node.inputs) > 1 and node.inputs[1] in g.nodes else 2)
+    w_nd = _weight_ndim(g, node)
 
     def roof(impl: str) -> float:
         c = matmul_cost(batch, m, n, k, eb, impl, n_epilogue=n_epi)
@@ -663,9 +698,7 @@ def assign_schedules(g: TaskGraph, cm: CostModel, backend: str = "tpu",
                     f"small-task serialized dim{d} (per-task {per_task:.0f} "
                     + ("bytes)" if moved is not None else "flops)"))
         if node.op == "matmul":
-            m, n = shape[-2], shape[-1]
-            node.schedule.tile = pick_matmul_tiles(m, n, node.attrs["k"],
-                                                   node.ttype.dtype, cm)
+            node.schedule.tile = matmul_tiles(g, node, cm)
         elif node.op == "attention":
             b, s, h, d_ = node.attrs["q_shape"]
             node.schedule.tile = pick_attention_tiles(s, node.attrs["kv_len"], d_,
@@ -698,6 +731,8 @@ def assign_schedules(g: TaskGraph, cm: CostModel, backend: str = "tpu",
             if node.attrs.get("exposed", False):
                 pick_impl(g, node, cm, backend, mesh_axes=mesh_axes,
                           forced=forced.get(node.op))
+                if node.op == "matmul":
+                    note_weight_passes(g, node)
             else:
                 node.schedule.impl = "opaque"
         node.schedule.serialized = all(

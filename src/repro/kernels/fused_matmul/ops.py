@@ -20,6 +20,13 @@ def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
+def row_block(bm: int, m: int) -> int:
+    """The kernel's row tile for a scheduled ``bm`` over ``m`` rows: blocks
+    obey the TPU's (8, 128) tiling whatever the schedule asked for.  Every
+    row block of the grid streams the whole weight once."""
+    return _round_up(min(bm, m), 8)
+
+
 def _classify(epilogue, out_shape):
     """Split dynamic operands from the static spec the kernel needs."""
     spec, operands = [], []
@@ -52,9 +59,7 @@ def fused_matmul(x, w, epilogue=None, tile=None, out_dtype=None,
     x2 = x.reshape(m, k)
 
     tile = tile or {}
-    # blocks obey the TPU's (8, 128) tiling whatever the schedule asked for
-    # (a [B, 1, d] decode activation is scheduled with one row per tile)
-    bm = _round_up(min(tile.get("bm", 128), m), 8)
+    bm = row_block(tile.get("bm", 128), m)
     bn = _round_up(min(tile.get("bn", 128), n), 128)
     bk = _round_up(min(tile.get("bk", 512), k), 128)
 

@@ -287,6 +287,66 @@ def test_cost_model_follows_the_device_kind():
 
 
 # ---------------------------------------------------------------------------
+# fused GEMM row tile: picked from the rows the kernel sees
+# ---------------------------------------------------------------------------
+
+_V5E = cost_model_for("TPU v5 lite")
+_ROW_TILE_CASES = [
+    # (label, activation, weight, expected tile; None: one row block)
+    ("granite_in_proj_decode", (96, 1, 4096), (4096, 16768), None),
+    ("qwen_head_decode", (16, 1, 2048), (2048, 151936), None),
+    ("prefill", (1, 4096, 2048), (2048, 2048),
+     {"bm": 512, "bn": 512, "bk": 2048}),
+    ("train", (2, 2048, 2048), (2048, 2048),
+     {"bm": 512, "bn": 512, "bk": 2048}),
+]
+
+
+def _scheduled_matmul(xs, ws, site):
+    """Capture one bf16 linear for the TPU target (shapes only, nothing
+    runs) and bind its schedule at ``site``: the compile pipeline's
+    ``assign_schedules`` or the derivation-time ``core.autodiff`` one."""
+    from repro.core.autodiff import _resolve_library_schedule
+    from repro.core.passes import optimize_graph, run_pipeline
+    x = jax.ShapeDtypeStruct(xs, jnp.bfloat16)
+    w = jax.ShapeDtypeStruct(ws, jnp.bfloat16)
+    with use(TapirConfig(mode="tapir", backend="tpu", cost_model=_V5E)):
+        g = tapir.capture_region(lambda x, w: tapir.linear(x, w), x, w)
+    if site == "assign_schedules":
+        run_pipeline(g, "tapir", _V5E, "tpu")
+    else:
+        optimize_graph(g, _V5E)
+    node = next(n for n in g.nodes.values() if n.op == "matmul")
+    if site == "autodiff":
+        _resolve_library_schedule(g, node, _V5E, "tpu", {}, {})
+    return g, node
+
+
+@pytest.mark.parametrize("site", ["assign_schedules", "autodiff"])
+@pytest.mark.parametrize("label,xs,ws,tile", _ROW_TILE_CASES,
+                         ids=[c[0] for c in _ROW_TILE_CASES])
+def test_fused_gemm_row_tile_covers_kernel_rows(site, label, xs, ws, tile):
+    """A [slots, 1, d] decode GEMM is one row block of the fused kernel
+    (its weight streams from HBM once a call); prefill and training keep
+    the tiles they had, since their first row tile was already the cap."""
+    g, node = _scheduled_matmul(xs, ws, site)
+    assert node.schedule.impl == "fused_kernel"
+    rows = int(np.prod(xs[:-1]))
+    if tile is None:
+        assert node.schedule.tile["bm"] == rows
+        note = f"weight passes: 1 (rows {rows}, bm {rows})"
+    else:
+        assert node.schedule.tile == tile
+        note = f"weight passes: {rows // 512} (rows {rows}, bm 512)"
+    assert note in node.schedule.notes
+    if site == "assign_schedules":
+        assert note in tapir.explain(g)
+    else:   # both sites take the same argmin
+        _, bound = _scheduled_matmul(xs, ws, "assign_schedules")
+        assert node.schedule.tile == bound.schedule.tile
+
+
+# ---------------------------------------------------------------------------
 # paged decode attention: the Pallas kernel reading pages in place vs the
 # gathered-view composite
 # ---------------------------------------------------------------------------

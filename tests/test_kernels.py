@@ -60,6 +60,33 @@ def test_fused_matmul_epilogues(epi):
     np.testing.assert_allclose(y, ref, rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("kind", ["row", "full"])
+def test_fused_matmul_row_tile_bitwise(kind):
+    """A [slots, 1, d] decode activation in one row block (bm = all rows,
+    the weight streamed once) gives the bits of 8-row blocks: the k-order
+    of every accumulation is the same."""
+    key = jax.random.PRNGKey(96)
+    slots, k, n = 96, 256, 384
+    x = jax.random.normal(key, (slots, 1, k)).astype(jnp.bfloat16)
+    w = jax.random.normal(jax.random.fold_in(key, 1), (k, n)).astype(
+        jnp.bfloat16)
+    shape = (n,) if kind == "row" else (slots, 1, n)
+    v = jax.random.normal(jax.random.fold_in(key, 2), shape).astype(
+        jnp.bfloat16)
+    epi = [("add", [v], {}), ("silu", [], {})]
+
+    def run(bm):
+        return np.asarray(fm_ops.fused_matmul(
+            x, w, epilogue=epi, tile={"bm": bm, "bn": 128, "bk": 128},
+            out_dtype="bfloat16", interpret=True), np.float32)
+
+    one = run(slots)
+    assert one.shape == (slots, 1, n)
+    assert fm_ops.row_block(slots, slots) == slots
+    assert fm_ops.row_block(1, slots) == 8
+    np.testing.assert_array_equal(one, run(8))
+
+
 # ---------------------------------------------------------------------------
 # flash attention
 # ---------------------------------------------------------------------------

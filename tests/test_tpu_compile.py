@@ -71,6 +71,8 @@ def _compile(fn, *sds):
 @pytest.mark.parametrize("m,k,n,epilogue", [
     (8, 2048, 2048, "row"),          # decode: 4-8 slots, bias epilogue
     (2048, 2048, 11008, "none"),     # prefill: the MLP up-projection
+    (96, 4096, 16768, "none"),       # decode: granite's Mamba2 in-projection
+                                     # at 96 slots, one row block of 96
 ])
 def test_fused_matmul_compiles(one_chip, m, k, n, epilogue):
     tile = pick_matmul_tiles(m, n, k, "bfloat16", V5E)
@@ -295,8 +297,10 @@ def test_mamba_slot_decode_binds_the_state_kernel_on_v5e(one_chip):
     """One decode step of a Mamba2 + MoE layer of granite-4.0-h-small
     (d_model 4096, 128 heads of 64, state 128; 2 of 72 experts held to
     keep the compile short) for 96 slots: the registry binds the state
-    kernel, and explain names the experts held."""
+    kernel, explain names the experts held, and every fused GEMM reads
+    its weight once."""
     import dataclasses
+    import re
 
     import repro.configs as C
     from repro.models.base import get_model
@@ -330,4 +334,8 @@ def test_mamba_slot_decode_binds_the_state_kernel_on_v5e(one_chip):
     tapir.clear_cache()
     assert "ssm_state_step" in report and "impl=kernel" in report
     assert "experts held: 0..1 of 72 routed" in report
+    # every fused decode GEMM (the in-projection among them) is one row
+    # block of the 96 slots: its weight streams once a step
+    passes = re.findall(r"weight passes: (\d+) \(rows (\d+)", report)
+    assert passes and set(passes) == {("1", "96")}
     assert "ssm_state_step" in text and "tpu_custom_call" in text
